@@ -64,10 +64,12 @@ def run_platform(spec):
     p.run(10)
     h = p.submit(spec)
     p.run(5)
-    assert h.acked, h.rejected
+    if not h.acked:
+        raise RuntimeError(f"platform rejected the job: {h.rejected}")
     state = p.run_until_terminal(h.job_id, timeout=3600)
     dt = time.perf_counter() - t0
-    assert state == "COMPLETED", state
+    if state != "COMPLETED":
+        raise RuntimeError(f"platform serve job ended {state}")
     responses = {}
     for r in range(spec.serve.requests):
         raw = p.objectstore.get(f"cos/{h.job_id}/responses/{r}")
@@ -132,4 +134,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

@@ -92,7 +92,9 @@ class ServeSpec:
     continuous: bool = False                  # continuous batching (paged)
     requests: int = 8                         # 0 = serve until halted
     page_budget: int = 0                      # 0 = worst case
-    use_pallas: bool = False                  # paged flash-decode kernel
+    # Pallas kernels (paged flash-decode, ...): None = on a TPU backend,
+    # the lax.scan walk elsewhere; True/False override
+    use_pallas: Optional[bool] = None
     ragged_prefill: Optional[bool] = None     # None = auto (attn-only archs)
     # optimistic admission: reserve worst-case pages up to overcommit ×
     # budget; on page exhaustion the engine evicts the youngest sequence
@@ -284,6 +286,12 @@ class JobSpec:
                 return "train.pending_stuck_s must be > 0"
             if w.helper_drain_s <= 0:
                 return "train.helper_drain_s must be > 0"
+            if w.use_pallas:
+                # jax.grad through a pallas_call fails, on a TPU and in
+                # interpret mode alike
+                return ("train.use_pallas: the Pallas kernels have no "
+                        "backward pass, so a training step cannot "
+                        "differentiate them; train with use_pallas=False")
             from repro.core.states import FAILURE_CATEGORIES
             for cat, budget in w.restart_budgets.items():
                 if cat not in FAILURE_CATEGORIES:

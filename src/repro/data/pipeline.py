@@ -11,6 +11,7 @@ learnable structure (cross-entropy decreases) while needing no files.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -25,6 +26,9 @@ class SyntheticLMData:
     seed: int = 0
     noise: float = 0.1            # fraction of purely-random tokens
 
+    # compiled once per dataset (the frozen dataclass is the static key):
+    # run eagerly, the scan below would trace and compile on every call
+    @functools.partial(jax.jit, static_argnums=0)
     def batch_at(self, step: int | jax.Array):
         """{tokens, labels}: labels[t] = tokens[t+1] (next-token LM)."""
         key = jax.random.fold_in(jax.random.key(self.seed), step)

@@ -167,7 +167,6 @@ def sharded_allreduce_int8(stacked: jax.Array, mesh,
     int8-wire sum, identical on every device by construction — psum
     output is replicated) comes back unsharded as ``shape``.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     cfg = cfg or CompressionConfig()
@@ -175,10 +174,27 @@ def sharded_allreduce_int8(stacked: jax.Array, mesh,
     def body(row: jax.Array) -> jax.Array:
         return allreduce_int8(row[0], axis, cfg)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=P(axis), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=P(axis), out_specs=P(),
+                       check_vma=False)
     return fn(stacked)
+
+
+def allreduce_int8_bound(stacked, cfg: Optional[CompressionConfig] = None):
+    """Per-element bound on ``|sharded_allreduce_int8 - exact sum|`` for
+    host ``stacked`` ``(ndev, *shape)``: ``ndev · scale / 2`` with each
+    chunk's shared (pmax) scale — see :func:`allreduce_int8`."""
+    import numpy as np
+    cfg = cfg or CompressionConfig()
+    x = np.asarray(stacked, np.float32)
+    ndev = x.shape[0]
+    flat = x.reshape(ndev, -1)
+    chunk = cfg.chunk_size or flat.shape[1]
+    pad = (-flat.shape[1]) % chunk
+    blocks = np.pad(flat, ((0, 0), (0, pad))).reshape(ndev, -1, chunk)
+    scales = (np.abs(blocks).max(axis=2) / cfg.levels).max(axis=0)
+    return (np.repeat(scales, chunk)[:flat.shape[1]]
+            .reshape(x.shape[1:]) * ndev / 2)
 
 
 def _int8_leaf(t: jax.Array, cfg: CompressionConfig) -> jax.Array:

@@ -7,15 +7,23 @@ table lists alternatives for both (absent axis names auto-drop):
 * the fixed production pod meshes, axes ``("pod", "data", "model")`` —
   what the dry-run compiles against;
 * generic ``("data", "fsdp", "tensor")`` meshes sized to whatever
-  devices exist — what a learner pod builds at startup, with a
-  single-host fallback so the same code path runs on one CPU.
+  devices exist — what a learner pod builds at startup.
+
+Every axis is ``AxisType.Auto``: the models place activations with
+``with_sharding_constraint`` (``models/layers.py``), which only accepts
+Auto axes.  ``jax.make_mesh`` would otherwise build Explicit ones.
 """
 from __future__ import annotations
 
 from typing import Dict, Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
+import numpy as np
+from jax.sharding import AbstractMesh, AxisType, Mesh
+
+
+def _auto(n: int):
+    return (AxisType.Auto,) * n
 
 
 def axis_sizes(mesh: Mesh) -> Dict[str, int]:
@@ -28,7 +36,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     data parallelism (cross-pod traffic = one gradient all-reduce/step)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_abstract_production_mesh(*, multi_pod: bool = False):
@@ -36,15 +44,22 @@ def make_abstract_production_mesh(*, multi_pod: bool = False):
     names/sizes, so placement analytics (``launch.specs.placement_report``)
     can price the 256/512-chip meshes on a single-CPU test host.
     ``NamedSharding.shard_shape`` works on it; compiling does not."""
-    from jax.sharding import AbstractMesh
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(shape, axes, axis_types=_auto(len(axes)))
 
 
-def make_host_mesh() -> Mesh:
-    """1-device mesh for CPU smoke tests (same code path as production)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
+    """(data, model) mesh over the first ``data × model`` local devices —
+    one device by default (CPU smoke tests, one-chip serving), the
+    production axis names so the same rules apply."""
+    n = data * model
+    devices = jax.devices()
+    if n > len(devices):
+        raise ValueError(f"mesh data={data} x model={model} needs {n} "
+                         f"devices; {len(devices)} present")
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2), devices=devices[:n])
 
 
 def make_device_mesh(
@@ -57,16 +72,16 @@ def make_device_mesh(
     """(data, fsdp, tensor) mesh over the available devices.
 
     ``data=None`` absorbs whatever devices remain after fsdp × tensor.
-    If the request doesn't fit the device count the mesh degrades to pure
-    data parallelism over every device (single-host fallback) — the same
-    step function still compiles, just without model sharding.
+    A request whose ``data × fsdp × tensor`` is not the device count
+    raises: a learner never silently runs with another layout.
     """
     devices = list(devices if devices is not None else jax.devices())
     n = len(devices)
     if data is None:
-        data = max(1, n // (fsdp * tensor))
-    if data * fsdp * tensor != n:
-        data, fsdp, tensor = n, 1, 1
-    import numpy as np
+        data = n // (fsdp * tensor)
+    if data < 1 or data * fsdp * tensor != n:
+        raise ValueError(
+            f"mesh data={data} x fsdp={fsdp} x tensor={tensor} does not "
+            f"match the {n} device(s) given")
     arr = np.asarray(devices).reshape(data, fsdp, tensor)
-    return Mesh(arr, ("data", "fsdp", "tensor"))
+    return Mesh(arr, ("data", "fsdp", "tensor"), axis_types=_auto(3))

@@ -21,8 +21,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 from repro.kernels.layout import KernelLayout, SpecDesc
 
 NEG_INF = -2.0e38
@@ -140,7 +140,7 @@ def flash_attention(
         out_specs=layout.out_block_specs()[0],
         out_shape=layout.out_shape_structs([q.dtype])[0],
         scratch_shapes=layout.scratch_shapes(),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=layout.dimension_semantics),
         interpret=interpret,
     )(q, k, v)

@@ -21,8 +21,12 @@ from repro.kernels.rglru_scan import rglru_scan as _rglru
 from repro.kernels.rwkv6_wkv import wkv6 as _wkv6
 
 
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return not on_tpu()
 
 
 @functools.partial(jax.jit, static_argnames=(

@@ -67,7 +67,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 from repro.kernels.layout import KernelLayout, SpecDesc
 
 NEG_INF = -2.0e38
@@ -360,7 +359,7 @@ def paged_decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=layout.out_shape_structs([q.dtype])[0],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=layout.dimension_semantics),
         interpret=interpret,
     )(page_table.astype(jnp.int32), pos_q.astype(jnp.int32), q,
@@ -481,7 +480,7 @@ def mla_paged_decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=layout.out_shape_structs([q_lat.dtype])[0],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=layout.dimension_semantics),
         interpret=interpret,
     )(page_table.astype(jnp.int32), pos_q.astype(jnp.int32), q_lat,

@@ -16,8 +16,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 from repro.kernels.layout import KernelLayout, SpecDesc
 
 
@@ -26,14 +26,16 @@ def rglru_layout(B: int, S: int, R: int, t_blk: int,
     """Grid layout of :func:`rglru_scan` — the single source of truth the
     pallas_call is built from and ``staticcheck`` abstractly checks."""
     seq_map = lambda bi, ri, ti: (bi, ti, ri)
-    h0_map = lambda bi, ri, ti: (bi, ri)
+    # h0 rides as (B, 1, R): a (1, r_blk) block of a 2-D (B, R) array
+    # breaks the TPU's (8, 128) tiling rule on its batch dim
+    h0_map = lambda bi, ri, ti: (bi, 0, ri)
     return KernelLayout(
         name="rglru_scan",
         grid=(B, R // r_blk, S // t_blk),
         in_specs=(
             SpecDesc("log_a", (B, S, R), (1, t_blk, r_blk), seq_map),
             SpecDesc("b", (B, S, R), (1, t_blk, r_blk), seq_map),
-            SpecDesc("h0", (B, R), (1, r_blk), h0_map),
+            SpecDesc("h0", (B, 1, R), (1, 1, r_blk), h0_map),
         ),
         out_specs=(
             SpecDesc("o", (B, S, R), (1, t_blk, r_blk), seq_map),
@@ -48,7 +50,7 @@ def _rglru_kernel(la_ref, b_ref, h0_ref, o_ref, carry, *, t_blk: int):
 
     @pl.when(ti == 0)
     def _init():
-        carry[...] = h0_ref[...]
+        carry[...] = h0_ref[0]
 
     h = carry[0]                                   # (r_blk,)
     la = la_ref[0]                                 # (t_blk, r_blk)
@@ -84,7 +86,7 @@ def rglru_scan(
         out_specs=layout.out_block_specs()[0],
         out_shape=layout.out_shape_structs([jnp.float32])[0],
         scratch_shapes=layout.scratch_shapes(),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=layout.dimension_semantics),
         interpret=interpret,
-    )(log_a, b, h0)
+    )(log_a, b, h0[:, None, :])

@@ -16,8 +16,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 from repro.kernels.layout import KernelLayout, SpecDesc
 
 
@@ -61,7 +61,13 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
     u = u_ref[0].astype(jnp.float32)               # (1, N)
     s = state[...]                                 # (N, N)
 
-    clw = jnp.cumsum(lw, axis=0)                   # inclusive
+    # inclusive prefix sum as a lower-triangular matmul: Mosaic has no
+    # cumsum; HIGHEST keeps the fp32 log-decays exact enough to exp
+    incl = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0) >= \
+        jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    clw = jax.lax.dot_general(incl.astype(jnp.float32), lw,
+                              (((1,), (0,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST)
     clw_ex = clw - lw                              # exclusive
     # inter-chunk: contribution of the carried state
     o_inter = jax.lax.dot_general(r * jnp.exp(clw_ex), s,
@@ -77,8 +83,9 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
     o_ref[0] = (o_inter + o_intra).astype(o_ref.dtype)
 
     # state update: decay to end of chunk + decayed outer products
-    k_dec = k * jnp.exp(clw[-1:] - clw)                          # (L, N)
-    s_new = jnp.exp(clw[-1])[:, None] * s + jax.lax.dot_general(
+    last = clw[L - 1:L]                                          # (1, N)
+    k_dec = k * jnp.exp(last - clw)                              # (L, N)
+    s_new = jnp.exp(last).T * s + jax.lax.dot_general(
         k_dec, v, (((0,), (0,)), ((), ())))
     state[...] = s_new
 
@@ -112,7 +119,7 @@ def wkv6(
         out_specs=layout.out_block_specs(),
         out_shape=layout.out_shape_structs([r.dtype, jnp.float32]),
         scratch_shapes=layout.scratch_shapes(),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=layout.dimension_semantics),
         interpret=interpret,
     )(r, k, v, lw, u, s0)

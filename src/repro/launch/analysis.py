@@ -1,5 +1,7 @@
 import os
+# a CPU-only compile tool: 512 fake host devices, never the chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Roofline cost extraction with scan-trip-count correction.
 
@@ -165,4 +167,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -1,5 +1,7 @@
 import os
+# a CPU-only compile tool: 512 fake host devices, never the chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
@@ -38,12 +40,8 @@ _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
 
 
 def cost_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` compat: jax < 0.5 returns a list with
-    one dict per computation, newer jax returns the dict directly."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """``Compiled.cost_analysis()``, empty when XLA reports nothing."""
+    return compiled.cost_analysis() or {}
 
 
 def parse_collectives(hlo_text: str) -> dict:
@@ -300,4 +298,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

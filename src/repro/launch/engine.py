@@ -203,7 +203,9 @@ class PagePool:
 
 def _set_page_tables(cache, host_table: np.ndarray):
     """Broadcast the (B, pps) host page table into every per-layer
-    ``page_table`` leaf (layers index their own pools identically)."""
+    ``page_table`` leaf (layers index their own pools identically).  Each
+    new leaf keeps the old one's placement, so the jitted steps see the
+    same input types and compile once."""
     import jax
     import jax.numpy as jnp
 
@@ -213,7 +215,8 @@ def _set_page_tables(cache, host_table: np.ndarray):
     out = []
     for path, leaf in leaves:
         if getattr(path[-1], "key", None) == "page_table":
-            out.append(jnp.broadcast_to(table, leaf.shape).astype(jnp.int32))
+            out.append(jax.device_put(
+                jnp.broadcast_to(table, leaf.shape), leaf.sharding))
         else:
             out.append(leaf)
     return jax.tree.unflatten(treedef, out)
@@ -325,9 +328,9 @@ class ServingEngine:
         self.prefill, self.decode = make_serve_steps(cfg, ctx)
         from repro.launch.specs import src_len_for
         self.src_len = src_len_for(cfg, self.max_len)
-        self.cache = init_cache(cfg, B, self.max_len, self.src_len,
-                                layout="paged", page_budget=budget,
-                                paged_tables="empty")
+        self.cache = self._on_mesh(init_cache(
+            cfg, B, self.max_len, self.src_len, layout="paged",
+            page_budget=budget, paged_tables="empty"))
 
         # page→data-shard locality (see PagePool); one shard when the budget
         # doesn't split evenly or a shard couldn't hold a full request
@@ -820,7 +823,18 @@ class ServingEngine:
         self.prefix_hits = st.get("prefix_hits", 0)
         self.prefix_misses = st.get("prefix_misses", 0)
         self.cow_copies = st.get("cow_copies", 0)
-        self.cache = jax.tree.map(jnp.asarray, snap["cache"])
+        self.cache = self._on_mesh(snap["cache"])
+
+    def _on_mesh(self, cache):
+        """Commit a fresh or restored cache to the serving mesh.  The
+        jitted steps return their cache on the mesh; a first input that
+        is not would give every step a second input type to compile."""
+        import jax
+        if self.ctx.mesh is None:
+            return jax.tree.map(jax.numpy.asarray, cache)
+        from jax.sharding import NamedSharding, PartitionSpec
+        return jax.device_put(
+            cache, NamedSharding(self.ctx.mesh, PartitionSpec()))
 
     # -- drive to completion --------------------------------------------------
     def run(self) -> None:
@@ -882,11 +896,9 @@ class RealServePayload:
         import dataclasses
 
         import jax
-        import jax.numpy as jnp
 
         from repro.configs import get_config
-        from repro.launch.executor import _make_mesh
-        from repro.models.layers import Ctx
+        from repro.launch.executor import serve_ctx
         from repro.models.params import init_params
 
         spec, sv = self.spec, self.spec.serve
@@ -897,9 +909,7 @@ class RealServePayload:
         if sv.page_size:
             overrides["page_size"] = sv.page_size
         cfg = dataclasses.replace(cfg, **overrides)
-        ctx = Ctx(mesh=_make_mesh(sv.mesh),
-                  dtype=jnp.float32 if sv.reduced else jnp.bfloat16,
-                  use_pallas=sv.use_pallas)
+        ctx = serve_ctx(sv)
         params = init_params(cfg, jax.random.key(spec.seed))
         engine = ServingEngine(cfg, ctx, params, sv)
         requests = synthesize_requests(cfg, sv, spec.seed, engine.ragged)

@@ -16,6 +16,7 @@ from ``repro.launch.serve``) for compatibility.
 from __future__ import annotations
 
 import dataclasses
+import math
 import subprocess
 import sys
 import time
@@ -54,6 +55,7 @@ def _run_train(spec: JobSpec) -> int:
 
     from repro.configs import RunConfig, get_config
     from repro.data.pipeline import SyntheticLMData
+    from repro.launch.specs import state_shardings
     from repro.models.layers import Ctx
     from repro.train.steps import init_train_state, make_train_step
 
@@ -69,25 +71,39 @@ def _run_train(spec: JobSpec) -> int:
                     learning_rate=t.learning_rate,
                     warmup_steps=max(t.total_steps // 20, 1),
                     total_steps=t.total_steps)
-    state = init_train_state(cfg, jax.random.key(spec.seed), run)
+    # on the mesh from the start: the step returns its state there, and a
+    # first input placed otherwise would compile the step twice
+    state = jax.device_put(init_train_state(cfg, jax.random.key(spec.seed),
+                                            run),
+                           state_shardings(cfg, mesh, run=run))
     data = SyntheticLMData(cfg.vocab_size, t.seq_len, t.global_batch,
                            spec.seed)
     step = jax.jit(make_train_step(cfg, ctx, run), donate_argnums=(0,))
 
     n_params = sum(x.size for x in jax.tree.leaves(state["params"]))
     print(f"[train] arch={cfg.name} params={n_params/1e6:.1f}M "
-          f"mesh={mesh.devices.shape} devices={mesh.devices.size}")
-    t0 = time.perf_counter()
+          f"mesh={mesh.devices.shape} devices={mesh.devices.size} "
+          f"backend={jax.default_backend()}")
+    losses = []
+    step_s = []
     for i in range(t.total_steps):
+        t0 = time.perf_counter()
         state, m = step(state, data.batch_at(i))
+        losses.append(float(m["loss"]))          # waits for the step
+        step_s.append(time.perf_counter() - t0)
         if i % t.log_every == 0 or i == t.total_steps - 1:
-            print(f"  step {i:5d}  loss {float(m['loss']):.4f}  "
+            print(f"  step {i:5d}  loss {losses[-1]:.4f}  "
                   f"gnorm {float(m['grad_norm']):.3f}  "
-                  f"lr {float(m['lr']):.2e}")
-    dt = time.perf_counter() - t0
-    tok = t.total_steps * t.global_batch * t.seq_len
-    print(f"[train] {t.total_steps} steps in {dt:.1f}s "
-          f"({tok/dt:.0f} tok/s incl. compile)")
+                  f"lr {float(m['lr']):.2e}  {step_s[-1]*1e3:.1f} ms")
+    tok = t.global_batch * t.seq_len
+    steady = step_s[1:]
+    print(f"[train] first step (trace + compile) {step_s[0]:.2f} s"
+          + (f"; steady {1e3*sum(steady)/len(steady):.1f} ms/step "
+             f"({tok*len(steady)/sum(steady):.0f} tok/s)" if steady else ""))
+    bad = [i for i, x in enumerate(losses) if not math.isfinite(x)]
+    if bad:
+        print(f"[train] FAIL: non-finite loss at step(s) {bad}")
+        return 1
     return 0
 
 
@@ -183,12 +199,24 @@ def run_continuous(cfg, ctx, params, sv: ServeSpec, seed: int = 0) -> int:
     return 0
 
 
-def _run_serve(spec: JobSpec) -> int:
-    import jax
+def serve_ctx(sv: ServeSpec):
+    """The forward-pass context a serve job runs under.  Kernels follow
+    the backend (Pallas on TPU, the scan walk elsewhere) unless the spec
+    says otherwise."""
     import jax.numpy as jnp
 
-    from repro.configs import get_config
+    from repro.kernels.ops import on_tpu
     from repro.models.layers import Ctx
+    return Ctx(mesh=_make_mesh(sv.mesh),
+               dtype=jnp.float32 if sv.reduced else jnp.bfloat16,
+               use_pallas=on_tpu() if sv.use_pallas is None
+               else sv.use_pallas)
+
+
+def _run_serve(spec: JobSpec) -> int:
+    import jax
+
+    from repro.configs import get_config
     from repro.models.params import init_params
 
     sv = spec.serve
@@ -205,9 +233,7 @@ def _run_serve(spec: JobSpec) -> int:
     if overrides:
         cfg = dataclasses.replace(cfg, **overrides)
 
-    mesh = _make_mesh(sv.mesh)
-    ctx = Ctx(mesh=mesh, dtype=jnp.float32 if sv.reduced else jnp.bfloat16,
-              use_pallas=sv.use_pallas)
+    ctx = serve_ctx(sv)
     params = init_params(cfg, jax.random.key(spec.seed))
 
     if sv.continuous:

@@ -1,5 +1,7 @@
 import os
+# a CPU-only compile tool: 512 fake host devices, never the chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """§Perf hillclimb harness: measure one cell under modified knobs.
 
@@ -25,13 +27,10 @@ ICI_BW = 50e9
 
 
 def measure(arch, shape, overrides, mb=None, csw=False, multi_pod=False):
-    import jax.numpy as jnp
     from repro.configs import get_config, get_run_config
     from repro.dist.sharding import DEFAULT_RULES
     from repro.launch.dryrun import build_lowered, cost_dict, parse_collectives
     from repro.launch.analysis import _variant_cfg, _extrapolate
-    from repro.models.layers import Ctx
-    from repro.launch.mesh import make_production_mesh
 
     run = get_run_config(arch, shape)
     if mb is not None:
@@ -61,7 +60,6 @@ def measure(arch, shape, overrides, mb=None, csw=False, multi_pod=False):
 
     # roofline terms from unrolled g=2/3 variants
     cfg = get_config(arch)
-    from repro.configs import SHAPES
     G = (cfg.num_layers - cfg.first_k_dense) // len(cfg.block_pattern)
     run1 = dataclasses.replace(run, num_microbatches=1)
     cs = {}
@@ -119,4 +117,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

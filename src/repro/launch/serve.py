@@ -54,9 +54,10 @@ def parse_spec(argv=None) -> JobSpec:
                          "pages up to overcommit × budget; page exhaustion "
                          "evicts the youngest sequence back to the queue "
                          "(1.0 = conservative, never evicts)")
-    ap.add_argument("--use-pallas", action="store_true",
-                    help="paged flash-decode Pallas kernel for decode "
-                         "(interpret mode off-TPU)")
+    ap.add_argument("--use-pallas", action=argparse.BooleanOptionalAction,
+                    default=None,
+                    help="Pallas kernels for decode (default: on a TPU "
+                         "backend only; interpret mode off-TPU)")
     ap.add_argument("--ragged-prefill", dest="ragged_prefill",
                     action="store_const", const=True, default=None,
                     help="force batched ragged prefill (default: auto for "
@@ -102,4 +103,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

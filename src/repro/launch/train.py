@@ -29,7 +29,8 @@ def parse_spec(argv=None) -> JobSpec:
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--remat", default="none", choices=["none", "dots", "full"])
     ap.add_argument("--mesh", default="host", choices=["host", "prod", "multipod"])
-    ap.add_argument("--use-pallas", action="store_true")
+    ap.add_argument("--use-pallas", action="store_true",
+                    help="refused until a Pallas kernel has a backward pass")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
@@ -58,4 +59,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

@@ -13,6 +13,7 @@ Layer stacks that repeat (the scan groups) carry a leading ``layers`` dim.
 """
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Dict, Optional, Tuple
@@ -307,6 +308,9 @@ def param_shardings(cfg: ModelConfig, mesh, rules=None) -> Tree:
                           DEFAULT_RULES if rules is None else rules)
 
 
+# one compiled program per config: run eagerly, every distinct leaf shape
+# compiles its own random and scaling ops, which costs a TPU minutes
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg: ModelConfig, key: jax.Array) -> Tree:
     """Concrete init.  Each leaf gets a key folded from its tree path, so
     adding/removing an unrelated leaf never reshuffles other leaves."""

@@ -63,3 +63,26 @@ def test_dryrun_cli_sweep_all_spec():
 def test_executor_rejects_invalid_spec():
     with pytest.raises(SystemExit, match="unknown framework"):
         execute(JobSpec(name="x", framework="not-a-framework"))
+
+
+def test_train_cli_refuses_pallas_kernels():
+    # no Pallas kernel has a backward pass: refused before any tracing
+    spec = train.parse_spec(["--reduced", "--steps", "1", "--use-pallas"])
+    assert spec.train.use_pallas
+    with pytest.raises(SystemExit, match="no backward pass"):
+        execute(spec)
+
+
+def test_serve_use_pallas_follows_the_backend(monkeypatch):
+    import repro.kernels.ops as ops
+    from repro.launch.executor import serve_ctx
+    auto = serve.parse_spec(["--reduced"]).serve
+    on = serve.parse_spec(["--reduced", "--use-pallas"]).serve
+    off = serve.parse_spec(["--reduced", "--no-use-pallas"]).serve
+    assert (auto.use_pallas, on.use_pallas, off.use_pallas) == \
+        (None, True, False)
+    assert [serve_ctx(sv).use_pallas for sv in (auto, on, off)] == \
+        [False, True, False]                     # this host is not a TPU
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    assert [serve_ctx(sv).use_pallas for sv in (auto, on, off)] == \
+        [True, True, False]

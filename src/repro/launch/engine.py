@@ -53,6 +53,7 @@ under the unchanged Guardian/LCM dependability machinery.
 from __future__ import annotations
 
 import hashlib
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional, Tuple
@@ -60,6 +61,7 @@ from typing import Deque, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core.jobspec import JobSpec, ServeSpec
+from repro.launch import spans
 
 #: Parent hash of a prompt's first page in the chained prefix hash.
 PREFIX_ROOT = "root"
@@ -209,17 +211,17 @@ def _set_page_tables(cache, host_table: np.ndarray):
     import jax
     import jax.numpy as jnp
 
-    table = jnp.asarray(host_table, jnp.int32)
-
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(cache)
-    out = []
-    for path, leaf in leaves:
-        if getattr(path[-1], "key", None) == "page_table":
-            out.append(jax.device_put(
-                jnp.broadcast_to(table, leaf.shape), leaf.sharding))
-        else:
-            out.append(leaf)
-    return jax.tree.unflatten(treedef, out)
+    with spans.span("engine.tables"):
+        table = jnp.asarray(host_table, jnp.int32)
+        leaves, treedef = jax.tree_util.tree_flatten_with_path(cache)
+        out = []
+        for path, leaf in leaves:
+            if getattr(path[-1], "key", None) == "page_table":
+                out.append(jax.device_put(
+                    jnp.broadcast_to(table, leaf.shape), leaf.sharding))
+            else:
+                out.append(leaf)
+        return jax.tree.unflatten(treedef, out)
 
 
 def _copy_pool_pages(cache, pairs: List[Tuple[int, int]]):
@@ -348,6 +350,9 @@ class ServingEngine:
         self.toks = np.zeros((B, 1), np.int64)
         self.pos = np.full((B,), -1, np.int64)
         self.queue: Deque[Request] = deque()
+        # request id -> when it (re)entered the queue, for its
+        # ``engine.queue`` span; host diagnostics, never snapshotted
+        self._queued_at: Dict[int, float] = {}
         self.responses: Dict[int, List[int]] = {}
         self.journal: List[dict] = []
 
@@ -387,6 +392,7 @@ class ServingEngine:
                 f"request {request.req} needs {need} pages worst-case; "
                 f"a shard holds {self.per_shard}")
         self.queue.append(request)
+        self._queued_at[request.req] = time.perf_counter()
 
     def free_slot_count(self) -> int:
         return sum(1 for s in self.slots if s is None)
@@ -471,10 +477,24 @@ class ServingEngine:
         physical pages are available.  Runs ONE batched ragged prefill for
         the whole round on attention-only stacks (per-slot view prefill
         otherwise).  Returns the admitted request ids."""
-        import jax.numpy as jnp
+        with spans.span("engine.admit") as sp:
+            with spans.span("engine.match"):
+                admitted, plans, cow_pairs = self._match_round()
+            out = self._prefill_round(admitted, plans, cow_pairs) \
+                if admitted else []
+            sp.attrs.update(rows=len(out), rids=tuple(out))
+        for rid in out:
+            t_queued = self._queued_at.pop(rid, None)
+            if t_queued is not None:
+                spans.mark("engine.queue", t_queued, sp.t0, rid=rid)
+        return out
 
-        from repro.models.model import (
-            cache_slot_merge, cache_slot_view, num_pages)
+    def _match_round(self) -> tuple:
+        """The round's slot loop: queue head into each free slot while its
+        prefix match, reservation and prompt pages allow.  Returns
+        ``(admitted, plans, cow_pairs)``: ``(slot, request)`` pairs, each
+        slot's ``(C, hashes, m)`` and the copy-on-write page pairs."""
+        from repro.models.model import num_pages
 
         admitted: List[tuple] = []               # (slot, request)
         plans: Dict[int, tuple] = {}             # slot -> (C, hashes, m)
@@ -538,9 +558,15 @@ class ServingEngine:
                 pending.update(ch for _, ch in hashes[m:L // self.ps])
             plans[b] = (C, hashes, m)
             admitted.append((b, req))
+        return admitted, plans, cow_pairs
 
-        if not admitted:
-            return []
+    def _prefill_round(self, admitted, plans, cow_pairs) -> List[int]:
+        """Prefill the round's admitted requests, publish their full
+        pages and record their first tokens.  Returns their ids."""
+        import jax.numpy as jnp
+
+        from repro.models.model import cache_slot_merge, cache_slot_view
+
         self.cache = _set_page_tables(self.cache, self.host_table)
 
         if self.ragged:
@@ -557,30 +583,37 @@ class ServingEngine:
                 toks_in[b, :len(r.tokens) - C] = r.tokens[C:]
                 lens[b] = len(r.tokens) - C
                 starts[b] = C
-            if cow_pairs:
-                self.cache = _copy_pool_pages(self.cache, cow_pairs)
-            if self.prefix_cache:
-                # chunked path even at starts == 0: one numeric family for
-                # every prefill, so evict-replay stays byte-identical
-                logits, self.cache = self.prefill(
-                    self.params, {"tokens": jnp.asarray(toks_in)},
-                    self.cache, jnp.asarray(lens), jnp.asarray(starts))
-            else:
-                logits, self.cache = self.prefill(
-                    self.params, {"tokens": jnp.asarray(toks_in)},
-                    self.cache, jnp.asarray(lens))
-            nxt_tok = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+            with spans.span("engine.prefill", slots=self.B, width=S0,
+                            tokens=int(lens.sum())):
+                if cow_pairs:
+                    self.cache = _copy_pool_pages(self.cache, cow_pairs)
+                if self.prefix_cache:
+                    # chunked path even at starts == 0: one numeric family
+                    # for every prefill, so evict-replay stays
+                    # byte-identical
+                    logits, self.cache = self.prefill(
+                        self.params, {"tokens": jnp.asarray(toks_in)},
+                        self.cache, jnp.asarray(lens), jnp.asarray(starts))
+                else:
+                    logits, self.cache = self.prefill(
+                        self.params, {"tokens": jnp.asarray(toks_in)},
+                        self.cache, jnp.asarray(lens))
+                nxt_tok = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
 
         out: List[int] = []
         for b, r in admitted:
             if not self.ragged:
-                view = cache_slot_view(self.cache, self.B, b)
-                batch = {"tokens": jnp.asarray(r.tokens[None])}
-                if self.cfg.is_encoder_decoder:
-                    batch["src_embeds"] = self._src_embeds(r.req)
-                logits, view = self.prefill(self.params, batch, view)
-                self.cache = cache_slot_merge(self.cache, view, self.B, b)
-                tok = int(jnp.argmax(logits[0, -1]))
+                L = len(r.tokens)
+                with spans.span("engine.prefill", slots=1, width=L,
+                                tokens=L):
+                    view = cache_slot_view(self.cache, self.B, b)
+                    batch = {"tokens": jnp.asarray(r.tokens[None])}
+                    if self.cfg.is_encoder_decoder:
+                        batch["src_embeds"] = self._src_embeds(r.req)
+                    logits, view = self.prefill(self.params, batch, view)
+                    self.cache = cache_slot_merge(self.cache, view, self.B,
+                                                  b)
+                    tok = int(jnp.argmax(logits[0, -1]))
             else:
                 tok = int(nxt_tok[b])
             rec = self.slots[b]
@@ -625,6 +658,7 @@ class ServingEngine:
         self.pos[b] = -1
         self.toks[b, 0] = 0
         self.queue.appendleft(rec.request)
+        self._queued_at[rec.request.req] = time.perf_counter()
         self.evictions += 1
         self.journal.append({"ev": "evict", "req": rec.request.req,
                              "slot": b})
@@ -676,29 +710,32 @@ class ServingEngine:
 
         if all(s is None for s in self.slots):
             return []
-        self._ensure_pages()
-        if all(s is None for s in self.slots):
-            return []                            # everything got evicted
-        logits, self.cache = self.decode(
-            self.params, {"tokens": jnp.asarray(self.toks)}, self.cache,
-            jnp.asarray(self.pos, jnp.int32))
-        self.decode_steps += 1
-        nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-        finished: List[int] = []
-        for b in range(self.B):
-            rec = self.slots[b]
-            if rec is None:
-                continue
-            tok = int(nxt[b])
-            self.toks[b, 0] = tok
-            self.pos[b] += 1
-            rec.out_tokens.append(tok)
-            self.generated += 1
-            rec.remaining -= 1
-            if rec.remaining <= 0:
-                finished.append(rec.request.req)
-                self.finish(b)
-        return finished
+        with spans.span("engine.step"):
+            with spans.span("engine.grow"):
+                self._ensure_pages()
+            if all(s is None for s in self.slots):
+                return []                        # everything got evicted
+            with spans.span("engine.decode"):
+                logits, self.cache = self.decode(
+                    self.params, {"tokens": jnp.asarray(self.toks)},
+                    self.cache, jnp.asarray(self.pos, jnp.int32))
+                nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+            self.decode_steps += 1
+            finished: List[int] = []
+            for b in range(self.B):
+                rec = self.slots[b]
+                if rec is None:
+                    continue
+                tok = int(nxt[b])
+                self.toks[b, 0] = tok
+                self.pos[b] += 1
+                rec.out_tokens.append(tok)
+                self.generated += 1
+                rec.remaining -= 1
+                if rec.remaining <= 0:
+                    finished.append(rec.request.req)
+                    self.finish(b)
+            return finished
 
     def finish(self, b: int) -> None:
         """Complete slot ``b``: free pages, release the reservation, log
@@ -706,19 +743,20 @@ class ServingEngine:
         re-execution after restore rewrites identical bytes)."""
         rec = self.slots[b]
         assert rec is not None, f"finish of empty slot {b}"
-        self.pool.free(rec.pages)
-        self.reserved[rec.shard] -= rec.need_worst
-        self.host_table[b, :] = -1
-        self.cache = _set_page_tables(self.cache, self.host_table)
-        prev = self.responses.get(rec.request.req)
-        assert prev is None or prev == rec.out_tokens, \
-            (rec.request.req, prev, rec.out_tokens)
-        self.responses[rec.request.req] = list(rec.out_tokens)
-        self.journal.append({"ev": "finish", "req": rec.request.req,
-                             "tokens": list(rec.out_tokens)})
-        self.slots[b] = None
-        self.pos[b] = -1
-        self.toks[b, 0] = 0
+        with spans.span("engine.finish", rid=rec.request.req):
+            self.pool.free(rec.pages)
+            self.reserved[rec.shard] -= rec.need_worst
+            self.host_table[b, :] = -1
+            self.cache = _set_page_tables(self.cache, self.host_table)
+            prev = self.responses.get(rec.request.req)
+            assert prev is None or prev == rec.out_tokens, \
+                (rec.request.req, prev, rec.out_tokens)
+            self.responses[rec.request.req] = list(rec.out_tokens)
+            self.journal.append({"ev": "finish", "req": rec.request.req,
+                                 "tokens": list(rec.out_tokens)})
+            self.slots[b] = None
+            self.pos[b] = -1
+            self.toks[b, 0] = 0
 
     # -- snapshot / restore --------------------------------------------------
     def snapshot(self) -> dict:
@@ -727,51 +765,55 @@ class ServingEngine:
         device state exactly — continuation is byte-identical."""
         import jax
 
-        def rec_doc(rec: Optional[SeqRecord]):
-            if rec is None:
-                return None
-            return {"req": rec.request.req,
-                    "tokens": np.asarray(rec.request.tokens).copy(),
-                    "gen_len": rec.request.gen_len,
-                    "pages": list(rec.pages), "shard": rec.shard,
-                    "need_worst": rec.need_worst,
-                    "remaining": rec.remaining,
-                    "out_tokens": list(rec.out_tokens),
-                    "admit_seq": rec.admit_seq,
-                    "n_shared": rec.n_shared,
-                    "cached_tokens": rec.cached_tokens}
+        with spans.span("engine.snapshot"):
+            def rec_doc(rec: Optional[SeqRecord]):
+                if rec is None:
+                    return None
+                return {"req": rec.request.req,
+                        "tokens": np.asarray(rec.request.tokens).copy(),
+                        "gen_len": rec.request.gen_len,
+                        "pages": list(rec.pages), "shard": rec.shard,
+                        "need_worst": rec.need_worst,
+                        "remaining": rec.remaining,
+                        "out_tokens": list(rec.out_tokens),
+                        "admit_seq": rec.admit_seq,
+                        "n_shared": rec.n_shared,
+                        "cached_tokens": rec.cached_tokens}
 
-        return {
-            "queue": [(r.req, np.asarray(r.tokens).copy(), r.gen_len)
-                      for r in self.queue],
-            "slots": [rec_doc(s) for s in self.slots],
-            "host_table": self.host_table.copy(),
-            "free_lists": [list(f) for f in self.pool.free_lists],
-            "high_water": self.pool.high_water,
-            "refcount": list(self.pool.refcount),
-            "page_meta": {int(p): {"parent": m["parent"], "hash": m["hash"],
-                                   "tokens": list(m["tokens"])}
-                          for p, m in self.pool.page_meta.items()},
-            "prefix_index": [{par: dict(kids) for par, kids in idx.items()}
-                             for idx in self.pool.prefix_index],
-            "reserved": list(self.reserved),
-            "toks": self.toks.copy(),
-            "pos": self.pos.copy(),
-            "responses": {r: list(t) for r, t in self.responses.items()},
-            "journal": [dict(e) for e in self.journal],
-            "stats": {"decode_steps": self.decode_steps,
-                      "generated": self.generated,
-                      "stalled_admissions": self.stalled_admissions,
-                      "evictions": self.evictions,
-                      "admit_seq": self._admit_seq,
-                      "prefill_tokens": self.prefill_tokens,
-                      "cached_tokens": self.cached_tokens,
-                      "prefix_hits": self.prefix_hits,
-                      "prefix_misses": self.prefix_misses,
-                      "cow_copies": self.cow_copies},
-            "journal_len": len(self.journal),
-            "cache": jax.device_get(self.cache),
-        }
+            return {
+                "queue": [(r.req, np.asarray(r.tokens).copy(), r.gen_len)
+                          for r in self.queue],
+                "slots": [rec_doc(s) for s in self.slots],
+                "host_table": self.host_table.copy(),
+                "free_lists": [list(f) for f in self.pool.free_lists],
+                "high_water": self.pool.high_water,
+                "refcount": list(self.pool.refcount),
+                "page_meta": {int(p): {"parent": m["parent"],
+                                       "hash": m["hash"],
+                                       "tokens": list(m["tokens"])}
+                              for p, m in self.pool.page_meta.items()},
+                "prefix_index": [{par: dict(kids)
+                                  for par, kids in idx.items()}
+                                 for idx in self.pool.prefix_index],
+                "reserved": list(self.reserved),
+                "toks": self.toks.copy(),
+                "pos": self.pos.copy(),
+                "responses": {r: list(t)
+                              for r, t in self.responses.items()},
+                "journal": [dict(e) for e in self.journal],
+                "stats": {"decode_steps": self.decode_steps,
+                          "generated": self.generated,
+                          "stalled_admissions": self.stalled_admissions,
+                          "evictions": self.evictions,
+                          "admit_seq": self._admit_seq,
+                          "prefill_tokens": self.prefill_tokens,
+                          "cached_tokens": self.cached_tokens,
+                          "prefix_hits": self.prefix_hits,
+                          "prefix_misses": self.prefix_misses,
+                          "cow_copies": self.cow_copies},
+                "journal_len": len(self.journal),
+                "cache": jax.device_get(self.cache),
+            }
 
     def restore(self, snap: dict) -> None:
         """Load a :meth:`snapshot` into this (freshly built) engine."""
@@ -781,6 +823,7 @@ class ServingEngine:
         self.queue = deque(Request(req=r, tokens=np.asarray(t),
                                    gen_len=g)
                            for r, t, g in snap["queue"])
+        self._queued_at = {}         # waits before the restore are unknown
         self.slots = []
         for doc in snap["slots"]:
             if doc is None:

@@ -483,6 +483,7 @@ def gqa_attention(
     return jnp.einsum("bshk,hkd->bsd", out, p["o"].astype(out.dtype)), new_cache
 
 
+@jax.named_scope("kv_write")
 def _write_full_kv(cache: Cache, k, v, pos, window: int) -> Cache:
     """Prefill: write rotated K/V into the cache buffer.
 
@@ -511,6 +512,7 @@ def _write_full_kv(cache: Cache, k, v, pos, window: int) -> Cache:
     return {"k": ck, "v": cv, "pos": cp}
 
 
+@jax.named_scope("kv_write")
 def _write_prefill_paged(cache: Cache, k, v,
                          lengths: Optional[jax.Array] = None) -> Cache:
     """Prefill into the paged layout: walk logical pages 0..ceil(S0/ps)-1 of
@@ -542,6 +544,7 @@ def _write_prefill_paged(cache: Cache, k, v,
     return {"k_pages": kp, "v_pages": vp, "page_table": pt}
 
 
+@jax.named_scope("kv_write")
 def _write_prefill_paged_offset(cache: Cache, k, v, lengths, pos) -> Cache:
     """Offset form of :func:`_write_prefill_paged` for chunked prefix
     prefill: the chunk's token ``s`` of row ``b`` lands at absolute
@@ -569,6 +572,7 @@ def _write_prefill_paged_offset(cache: Cache, k, v, lengths, pos) -> Cache:
     return {"k_pages": kp, "v_pages": vp, "page_table": pt}
 
 
+@jax.named_scope("kv_write")
 def _write_prefill_ring_ragged(cache: Cache, k, v, lengths: jax.Array,
                                window: int) -> Cache:
     """Ragged prefill into a ring buffer: row ``b`` keeps the last
@@ -602,6 +606,7 @@ def _write_prefill_ring_ragged(cache: Cache, k, v, lengths: jax.Array,
     return {"k": ck, "v": cv, "pos": cp}
 
 
+@jax.named_scope("kv_write")
 def _update_decode_kv(cache: Cache, k, v, pos, window: int):
     """Insert one token's K/V; return (new_cache, k_all, v_all, pos_all).
     ``k, v`` arrive as (B, 1, K, hd); cache layout is (B, K, S, hd).
@@ -642,6 +647,7 @@ def _update_decode_kv(cache: Cache, k, v, pos, window: int):
     return {"k": ck, "v": cv, "pos": cp}, ck, cv, cp
 
 
+@jax.named_scope("kv_write")
 def _update_decode_kv_paged(cache: Cache, k, v, pos) -> Cache:
     """Insert one token's K/V into the page pool.  ``k, v`` arrive as
     (B, 1, K, hd); ``pos`` is scalar or (B,).  Rows with pos < 0 (inactive
@@ -665,6 +671,7 @@ def _update_decode_kv_paged(cache: Cache, k, v, pos) -> Cache:
 # ---------------------------------------------------------------------------
 # MLA (DeepSeek-V2)
 # ---------------------------------------------------------------------------
+@jax.named_scope("kv_write")
 def _write_prefill_latent_paged(cache: Cache, ckv, krope, lengths,
                                 pos) -> Cache:
     """Scatter a prefill chunk's latents into the paged latent pool.
@@ -691,6 +698,7 @@ def _write_prefill_latent_paged(cache: Cache, ckv, krope, lengths,
     return {"ckv_pages": cp, "krope_pages": rp, "page_table": pt}
 
 
+@jax.named_scope("kv_write")
 def _update_decode_latent_paged(cache: Cache, ckv, krope, pos) -> Cache:
     """Insert one token's latent into the page pool.  ``ckv (B, lora)`` /
     ``krope (B, rd)``; ``pos`` is scalar or (B,).  Rows with pos < 0

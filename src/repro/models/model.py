@@ -75,13 +75,14 @@ def apply_block(
                       ("batch", "seq", "embed_act"))
     if kind in (GLOBAL_ATTN, LOCAL_ATTN):
         sub = cache.get("attn") if cache is not None else None
-        if cfg.use_mla:
-            y, nc = mla_attention(cfg, p["attn"], x, ctx, mode=amode,
-                                  cache=sub, pos=pos, lengths=lengths)
-        else:
-            y, nc = gqa_attention(cfg, p["attn"], x, ctx, kind=kind,
-                                  mode=amode, cache=sub, pos=pos,
-                                  causal=causal, lengths=lengths)
+        with jax.named_scope("attention"):
+            if cfg.use_mla:
+                y, nc = mla_attention(cfg, p["attn"], x, ctx, mode=amode,
+                                      cache=sub, pos=pos, lengths=lengths)
+            else:
+                y, nc = gqa_attention(cfg, p["attn"], x, ctx, kind=kind,
+                                      mode=amode, cache=sub, pos=pos,
+                                      causal=causal, lengths=lengths)
         if new_cache is not None:
             new_cache["attn"] = nc
     elif kind == RECURRENT:
@@ -133,10 +134,12 @@ def apply_block(
         x = ctx.constrain(rms_norm(h, p["ffn_norm"], cfg.norm_eps),
                           ("batch", "seq", "embed_act"))
         if "moe" in p and not dense_only:
-            y, aux = moe_ffn(cfg, p["moe"], x, ctx,
-                             dropless=mode != "train")
+            with jax.named_scope("moe"):
+                y, aux = moe_ffn(cfg, p["moe"], x, ctx,
+                                 dropless=mode != "train")
         else:
-            y = dense_ffn(p["ffn"], x, cfg.act, ctx)
+            with jax.named_scope("ffn"):
+                y = dense_ffn(p["ffn"], x, cfg.act, ctx)
     y = ctx.constrain(y, ("batch", "resid_seq", "embed_act"))
     h = h + _post("post_ffn_norm", y)
     h = ctx.constrain(h, ("batch", "resid_seq", "embed_act"))
@@ -270,6 +273,7 @@ def _embed(cfg: ModelConfig, params: Tree, tokens: jax.Array, ctx: Ctx) -> jax.A
     return ctx.constrain(h, ("batch", "seq", "embed_act"))
 
 
+@jax.named_scope("logits")
 def _unembed(cfg: ModelConfig, params: Tree, h: jax.Array, ctx: Ctx) -> jax.Array:
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     table = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

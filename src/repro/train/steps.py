@@ -159,6 +159,7 @@ def make_prefill_step(cfg: ModelConfig, ctx: Ctx):
     ``starts`` (B,) additionally makes it *chunked* (prefix caching): row
     ``b``'s tokens are the uncached tail of its prompt, opening at
     absolute position ``starts[b]``."""
+    @jax.named_scope("prefill")
     def prefill_step(params, batch, cache, lengths=None, starts=None):
         logits, new_cache, _ = forward(cfg, params, batch, ctx,
                                        mode="prefill", cache=cache,
@@ -169,6 +170,7 @@ def make_prefill_step(cfg: ModelConfig, ctx: Ctx):
 
 def make_decode_step(cfg: ModelConfig, ctx: Ctx):
     """(params, tokens (B,1), cache, pos scalar|(B,)) -> (logits, cache)."""
+    @jax.named_scope("decode")
     def decode_step(params, batch, cache, pos):
         logits, new_cache, _ = forward(cfg, params, batch, ctx,
                                        mode="decode", cache=cache, pos=pos)

@@ -7,21 +7,22 @@ job.  :class:`ServingEngine` is that loop turned into an explicit state
 machine:
 
 * **admit** — one admission round: FIFO requests from the queue into free
-  decode slots, gated by a per-shard *worst-case page reservation* scaled
-  by ``ServeSpec.overcommit`` (1.0 = the old conservative admission;
-  > 1.0 = optimistic admission with preemption).  Pages are allocated
-  lazily (prompt pages at admission, one page at a time as decode grows),
-  so overcommitted admission can actually run out — see evict.  With
-  **prefix caching** on (the default for all-global paged decoders), each
-  prompt's full pages are chain-hashed against the shard's prefix index:
-  hits are attached read-only with a refcount bump — no prefill compute,
-  no new residency — a first-divergent-token overlap gets its page
-  copy-on-write duplicated, and the round's single ragged prefill covers
-  only the uncached tails (at per-row start offsets).  A request whose
-  prefix is being prefilled by an earlier request in the same round
-  defers one round and attaches instead of recomputing, so N requests
-  sharing a P-token prefix pay ~one prefill and one set of resident
-  prefix pages.
+  decode slots, each prefilled alone in a one-row view of the cache (rows
+  mid-decode cost the round nothing), gated by a per-shard *worst-case
+  page reservation* scaled by ``ServeSpec.overcommit`` (1.0 = the old
+  conservative admission; > 1.0 = optimistic admission with preemption).
+  Pages are allocated lazily (prompt pages at admission, one page at a
+  time as decode grows), so overcommitted admission can actually run out
+  — see evict.  With **prefix caching** on (the default for all-global
+  paged decoders), each prompt's full pages are chain-hashed against the
+  shard's prefix index: hits are attached read-only with a refcount bump
+  — no prefill compute, no new residency — a first-divergent-token
+  overlap gets its page copy-on-write duplicated, and each admitted
+  request's prefill covers only its uncached tail (at its start offset).
+  A request whose prefix is being prefilled by an earlier request in the
+  same round defers one round and attaches instead of recomputing, so N
+  requests sharing a P-token prefix pay ~one prefill and one set of
+  resident prefix pages.
 * **step** — one batched decode step over every active slot; grows each
   sequence's page list on demand first.  On page exhaustion the engine
   **evicts the youngest sequence in the starving shard** back to the front
@@ -294,17 +295,18 @@ class ServingEngine:
         # run_continuous maps them to SystemExit for the CLI
         if cfg.cache_layout != "paged":
             raise ValueError("--continuous requires --layout paged")
-        # ragged (one batched prefill per admission round) covers every
-        # decoder-only stack: paged globals + ring locals mask their
-        # writes, paged MLA latents scatter per row, recurrent/RWKV
-        # carries are length-masked.  Enc-dec keeps the per-slot path —
-        # the cross K/V of rows not in the round would be overwritten.
+        # ragged (each admitted prompt padded to a page multiple, the
+        # padding masked by its length) covers every decoder-only stack:
+        # paged globals + ring locals mask their writes, paged MLA
+        # latents scatter per row, recurrent/RWKV carries are
+        # length-masked.  Enc-dec prefills the exact prompt with its
+        # encoder input (the model's ragged path has no cross-attention).
         ragged_ok = not cfg.is_encoder_decoder
         ragged = ragged_ok if sv.ragged_prefill is None else sv.ragged_prefill
         if ragged and not ragged_ok:
             raise ValueError(
-                "--ragged-prefill needs a decoder-only stack; the encoder "
-                "output is per-round, so enc-dec prefills per slot")
+                "--ragged-prefill needs a decoder-only stack; enc-dec "
+                "prefills each prompt at its exact length")
         # hash-addressed prefix caching: needs the chunked-prefill seam,
         # which covers all-global paged decoders only (ring locals would
         # have to replay the evicted prefix; vision frontends shift pos 0)
@@ -474,9 +476,9 @@ class ServingEngine:
     def admit(self) -> List[int]:
         """One admission round: FIFO queue head into free slots while the
         shard reservation (scaled by ``overcommit``) and the prompt's
-        physical pages are available.  Runs ONE batched ragged prefill for
-        the whole round on attention-only stacks (per-slot view prefill
-        otherwise).  Returns the admitted request ids."""
+        physical pages are available, then prefills each admitted request
+        in a one-row view of the cache.  Returns the admitted request
+        ids."""
         with spans.span("engine.admit") as sp:
             with spans.span("engine.match"):
                 admitted, plans, cow_pairs = self._match_round()
@@ -561,63 +563,50 @@ class ServingEngine:
         return admitted, plans, cow_pairs
 
     def _prefill_round(self, admitted, plans, cow_pairs) -> List[int]:
-        """Prefill the round's admitted requests, publish their full
-        pages and record their first tokens.  Returns their ids."""
+        """Prefill each admitted request alone, in a one-row view of the
+        cache, publish its full pages and record its first token.
+        Returns their ids.
+
+        A row computes only its own uncached tail: rows mid-decode and
+        the round's other rows cost nothing.  Ragged prefill pads the
+        tail to a page multiple (one compile per width bucket, whatever
+        the slot or the round's size) and masks the padding by
+        ``lengths``; the per-slot path prefills the exact prompt."""
         import jax.numpy as jnp
 
         from repro.models.model import cache_slot_merge, cache_slot_view
 
         self.cache = _set_page_tables(self.cache, self.host_table)
-
-        if self.ragged:
-            # one batched ragged prefill for the whole round over the
-            # UNCACHED prompt tails only: pad to the round's max tail,
-            # bucketed to a page multiple (bounds recompiles)
-            round_max = max(len(r.tokens) - plans[b][0] for b, r in admitted)
-            S0 = -(-round_max // self.ps) * self.ps
-            toks_in = np.zeros((self.B, S0), admitted[0][1].tokens.dtype)
-            lens = np.zeros((self.B,), np.int32)
-            starts = np.zeros((self.B,), np.int32)
-            for b, r in admitted:
-                C = plans[b][0]
-                toks_in[b, :len(r.tokens) - C] = r.tokens[C:]
-                lens[b] = len(r.tokens) - C
-                starts[b] = C
-            with spans.span("engine.prefill", slots=self.B, width=S0,
-                            tokens=int(lens.sum())):
-                if cow_pairs:
-                    self.cache = _copy_pool_pages(self.cache, cow_pairs)
-                if self.prefix_cache:
-                    # chunked path even at starts == 0: one numeric family
-                    # for every prefill, so evict-replay stays
-                    # byte-identical
-                    logits, self.cache = self.prefill(
-                        self.params, {"tokens": jnp.asarray(toks_in)},
-                        self.cache, jnp.asarray(lens), jnp.asarray(starts))
-                else:
-                    logits, self.cache = self.prefill(
-                        self.params, {"tokens": jnp.asarray(toks_in)},
-                        self.cache, jnp.asarray(lens))
-                nxt_tok = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-
         out: List[int] = []
         for b, r in admitted:
-            if not self.ragged:
-                L = len(r.tokens)
-                with spans.span("engine.prefill", slots=1, width=L,
-                                tokens=L):
-                    view = cache_slot_view(self.cache, self.B, b)
-                    batch = {"tokens": jnp.asarray(r.tokens[None])}
-                    if self.cfg.is_encoder_decoder:
-                        batch["src_embeds"] = self._src_embeds(r.req)
-                    logits, view = self.prefill(self.params, batch, view)
-                    self.cache = cache_slot_merge(self.cache, view, self.B,
-                                                  b)
-                    tok = int(jnp.argmax(logits[0, -1]))
-            else:
-                tok = int(nxt_tok[b])
-            rec = self.slots[b]
             C, hashes, m = plans[b]
+            tail = r.tokens[C:]
+            width = -(-len(tail) // self.ps) * self.ps if self.ragged \
+                else len(tail)
+            with spans.span("engine.prefill", slots=1, width=width,
+                            tokens=len(tail)):
+                if cow_pairs:
+                    # every copy of the round, before its first prefill
+                    self.cache = _copy_pool_pages(self.cache, cow_pairs)
+                    cow_pairs = []
+                toks = np.zeros((1, width), tail.dtype)
+                toks[0, :len(tail)] = tail
+                batch = {"tokens": jnp.asarray(toks)}
+                if self.cfg.is_encoder_decoder:
+                    batch["src_embeds"] = self._src_embeds(r.req)
+                per_row = ()
+                if self.ragged:
+                    per_row = (jnp.asarray([len(tail)], jnp.int32),)
+                if self.prefix_cache:
+                    # chunked path even at C == 0: one numeric family for
+                    # every prefill, so evict-replay stays byte-identical
+                    per_row += (jnp.asarray([C], jnp.int32),)
+                view = cache_slot_view(self.cache, self.B, b)
+                logits, view = self.prefill(self.params, batch, view,
+                                            *per_row)
+                self.cache = cache_slot_merge(self.cache, view, self.B, b)
+                tok = int(jnp.argmax(logits[0, -1]))
+            rec = self.slots[b]
             if self.prefix_cache:
                 # the round's freshly prefilled full pages become cache
                 # content (including a full CoW page — its bytes are now
@@ -869,12 +858,13 @@ class ServingEngine:
         self.cache = self._on_mesh(snap["cache"])
 
     def _on_mesh(self, cache):
-        """Commit a fresh or restored cache to the serving mesh.  The
+        """Commit a fresh or restored cache to the serving mesh (without
+        one, to the default device).  The
         jitted steps return their cache on the mesh; a first input that
         is not would give every step a second input type to compile."""
         import jax
         if self.ctx.mesh is None:
-            return jax.tree.map(jax.numpy.asarray, cache)
+            return jax.device_put(cache, jax.devices()[0])
         from jax.sharding import NamedSharding, PartitionSpec
         return jax.device_put(
             cache, NamedSharding(self.ctx.mesh, PartitionSpec()))

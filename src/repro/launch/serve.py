@@ -60,11 +60,12 @@ def parse_spec(argv=None) -> JobSpec:
                          "backend only; interpret mode off-TPU)")
     ap.add_argument("--ragged-prefill", dest="ragged_prefill",
                     action="store_const", const=True, default=None,
-                    help="force batched ragged prefill (default: auto for "
-                         "attention-only archs)")
+                    help="force ragged prefill, each prompt padded to a "
+                         "page multiple (default: every decoder-only arch)")
     ap.add_argument("--no-ragged-prefill", dest="ragged_prefill",
                     action="store_const", const=False,
-                    help="force per-slot lockstep prefill")
+                    help="force exact-length prefill (one compile per "
+                         "prompt length)")
     ap.add_argument("--no-prefix-cache", dest="prefix_cache",
                     action="store_false", default=True,
                     help="disable hash-addressed prefix caching / "

@@ -621,7 +621,9 @@ def _is_shared_pos(path, leaf, batch_size: int, axis: int) -> bool:
 
 
 def cache_slot_view(cache: Tree, batch_size: int, b: int) -> Tree:
-    """Batch-1 view of ``cache`` for slot ``b`` (page pools shared)."""
+    """Batch-1 view of ``cache`` for slot ``b`` (page pools shared).  The
+    slot index is an operand of the slice, so views of every slot share
+    one compiled slice per leaf shape."""
     leaves, treedef = jax.tree_util.tree_flatten_with_path(cache)
     out = []
     for path, leaf in leaves:
@@ -629,20 +631,24 @@ def cache_slot_view(cache: Tree, batch_size: int, b: int) -> Tree:
         if _is_pool(path) or _is_shared_pos(path, leaf, batch_size, ax):
             out.append(leaf)
         else:
-            out.append(jax.lax.slice_in_dim(leaf, b, b + 1, axis=ax))
+            out.append(jax.lax.dynamic_slice_in_dim(leaf, b, 1, axis=ax))
     return jax.tree.unflatten(treedef, out)
 
 
 def cache_slot_merge(cache: Tree, view: Tree, batch_size: int, b: int) -> Tree:
     """Write a slot view (as returned by prefill) back into the full cache:
-    pool leaves replace wholesale, per-sequence leaves update row ``b``."""
+    pool leaves replace wholesale, per-sequence leaves update row ``b``.
+    Page tables are the host's and no step writes them, so the cache's
+    own are kept."""
     leaves, treedef = jax.tree_util.tree_flatten_with_path(cache)
     vleaves = jax.tree.leaves(view)
     assert len(leaves) == len(vleaves), (len(leaves), len(vleaves))
     out = []
     for (path, leaf), vleaf in zip(leaves, vleaves):
         ax = _slot_axis(path)
-        if _is_pool(path) or _is_shared_pos(path, leaf, batch_size, ax):
+        if getattr(path[-1], "key", None) == "page_table":
+            out.append(leaf)
+        elif _is_pool(path) or _is_shared_pos(path, leaf, batch_size, ax):
             out.append(vleaf)
         else:
             out.append(jax.lax.dynamic_update_slice_in_dim(

@@ -1,9 +1,10 @@
 """The program's spans (``launch/spans.py``) and where the serving engine
 records them: one ``engine.admit`` per round with its ``engine.match``,
-``engine.tables`` and ``engine.prefill`` inside it, one ``engine.queue``
-per admission (again after an eviction), one ``engine.step`` per decode
-step with its ``engine.grow``, ``engine.decode`` and ``engine.finish``
-inside it; the ring stays bounded and no span reaches a snapshot."""
+``engine.tables`` and one ``engine.prefill`` per admitted request inside
+it, one ``engine.queue`` per admission (again after an eviction), one
+``engine.step`` per decode step with its ``engine.grow``,
+``engine.decode`` and ``engine.finish`` inside it; the ring stays
+bounded and no span reaches a snapshot."""
 import dataclasses
 import time
 
@@ -97,8 +98,9 @@ def test_ring_is_bounded(capacity, added):
 def test_admission_round_spans(model):
     """Three prompts sharing two pages: round 1 prefills the leader (the
     followers wait for its pages), round 2 the followers' tails.  Each
-    round is one ``engine.admit`` whose prefill computes B x S0 token
-    rows for the admitted requests' uncached tails."""
+    round is one ``engine.admit`` holding one ``engine.prefill`` per
+    admitted request: one row, as wide as its own uncached tail rounded
+    up to a page."""
     eng = _engine(model, batch=4)
     prompts = _prompts(3, 20, shared=16)
     t_submit = time.perf_counter()
@@ -115,12 +117,15 @@ def test_admission_round_spans(model):
         assert {s.name for s in kids} == {"engine.match", "engine.tables",
                                           "engine.prefill"}
         assert all(_inside(s, adm) for s in kids)
-        (pre,) = _named(recs, "engine.prefill")
+        pres = _named(recs, "engine.prefill")
         tails = [len(prompts[e["req"]]) - e["cached"]
                  for e in eng.journal[mark:] if e["ev"] == "admit"]
-        assert pre.attrs["tokens"] == sum(tails)
-        S0 = -(-max(tails) // eng.ps) * eng.ps
-        assert pre.attrs["slots"] * pre.attrs["width"] == eng.B * S0
+        assert len(pres) == len(tails) == want
+        assert all(p.parent == "engine.admit" for p in pres)
+        assert [p.attrs for p in pres] == [
+            {"slots": 1, "width": -(-t // eng.ps) * eng.ps, "tokens": t}
+            for t in tails]
+        assert sum(p.attrs["tokens"] for p in pres) == sum(tails)
         # each admitted request waited from its submit to the round
         queued = [s for s in _named(spans.between(t_submit, adm.t1),
                                     "engine.queue") if s.t1 == adm.t0]

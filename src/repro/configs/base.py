@@ -62,6 +62,15 @@ class ModelConfig:
     query_pre_attn_scalar: float = 0.0  # overrides 1/sqrt(head_dim) when > 0
     rope_theta: float = 10_000.0
     tie_embeddings: bool = False
+    # YaRN RoPE scaling (DeepSeek-V2's ``rope_scaling``); factor 1 = plain
+    # RoPE.  Applied on MLA's rope dims; ``yarn_mscale`` is its
+    # ``mscale_all_dim``, folded into the softmax scale as mscale^2 (its
+    # ``mscale`` equals it, so the rope's cos/sin factor is 1).
+    rope_scaling_factor: float = 1.0
+    rope_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 0.0
 
     # --- MLA (DeepSeek-V2 multi-head latent attention) ----------------------
     use_mla: bool = False
@@ -80,6 +89,19 @@ class ModelConfig:
     capacity_factor: float = 1.25
     router_aux_loss_coef: float = 0.001
     moe_group_size: int = 1024        # tokens per dispatch group (§Perf knob)
+    # routing: softmax over all E, then (with n_group > 1) DeepSeek-V2's
+    # group_limited_greedy — keep the topk_group groups whose best expert
+    # scores highest, top-k inside them; weights renormalised to sum 1
+    # (norm_topk_prob) and multiplied by routed_scaling_factor
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    n_group: int = 1
+    topk_group: int = 1
+    # the experts this layer holds: ``num_experts_held`` (0 = all E) from
+    # ``first_held_expert`` on — one chip's share under expert parallelism.
+    # The router still scores all E; the layer adds only its experts' part.
+    num_experts_held: int = 0
+    first_held_expert: int = 0
 
     # --- recurrence (RG-LRU) ------------------------------------------------
     rnn_width: int = 0
@@ -125,6 +147,19 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def experts_held(self) -> int:
+        return self.num_experts_held or self.num_experts
+
+    @property
+    def rope_yarn(self) -> Optional[Tuple[float, int, float, float]]:
+        """``(factor, original_max_pos, beta_fast, beta_slow)`` where YaRN
+        scales the rope, else None."""
+        if self.rope_scaling_factor == 1.0:
+            return None
+        return (self.rope_scaling_factor, self.rope_original_max_pos,
+                self.yarn_beta_fast, self.yarn_beta_slow)
 
     @property
     def uses_attention(self) -> bool:
@@ -190,6 +225,11 @@ class ModelConfig:
             num_shared_experts=min(self.num_shared_experts, 1),
             moe_d_ff=32 if self.moe_d_ff else 0,
             first_k_dense=min(self.first_k_dense, 1),
+            n_group=min(self.n_group, 2),
+            topk_group=min(self.topk_group, 1),
+            num_experts_held=0,
+            first_held_expert=0,
+            rope_original_max_pos=min(self.rope_original_max_pos, 16),
             rnn_width=64 if self.rnn_width else 0,
             rwkv_ddlerp_rank=8,
             rwkv_decay_rank=8,

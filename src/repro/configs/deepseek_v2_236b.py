@@ -1,5 +1,7 @@
 """DeepSeek-V2 (236B, 21B active): MLA (kv_lora=512) + MoE 160 routed top-6
-with 2 shared experts; first layer dense. [arXiv:2405.04434]"""
+with 2 shared experts; first layer dense; group-limited greedy routing (8
+groups, top-3) with unnormalised weights scaled by 16; YaRN RoPE (factor
+40 over 4096 positions). [arXiv:2405.04434, hf:deepseek-ai/DeepSeek-V2]"""
 from repro.configs.base import GLOBAL_ATTN, ModelConfig, RunConfig, register, register_run
 
 CONFIG = register(ModelConfig(
@@ -24,7 +26,16 @@ CONFIG = register(ModelConfig(
     num_shared_experts=2,
     moe_d_ff=1536,
     first_k_dense=1,
+    norm_topk_prob=False,
+    routed_scaling_factor=16.0,
+    n_group=8,
+    topk_group=3,
     rope_theta=10_000.0,
+    rope_scaling_factor=40.0,
+    rope_original_max_pos=4096,
+    yarn_beta_fast=32.0,
+    yarn_beta_slow=1.0,
+    yarn_mscale=0.707,
 ))
 
 # 236B params on 256 × 16 GB chips: fp32 master + fp32 moments alone would be

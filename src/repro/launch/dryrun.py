@@ -147,8 +147,10 @@ def build_lowered(arch: str, shape_name: str, multi_pod: bool,
         psh = S.param_shardings(cfg, mesh, rules)
         csp = S.cache_specs(cfg, shape, run)
         csh = S.cache_shardings(cfg, shape, mesh, rules, run)
+        # (logits, cache), and a MoE stack's expert counts
+        out_sh = (None, csh) + ((None,) if cfg.is_moe else ())
         fn = jax.jit(step, in_shardings=(psh, bsh, csh),
-                     out_shardings=(None, csh), donate_argnums=(2,))
+                     out_shardings=out_sh, donate_argnums=(2,))
         lowered = fn.lower(psp, bs, csp)
     else:  # decode
         step = make_decode_step(cfg, ctx)
@@ -156,8 +158,9 @@ def build_lowered(arch: str, shape_name: str, multi_pod: bool,
         psh = S.param_shardings(cfg, mesh, rules)
         csp = S.cache_specs(cfg, shape, run)
         csh = S.cache_shardings(cfg, shape, mesh, rules, run)
+        out_sh = (None, csh) + ((None,) if cfg.is_moe else ())
         fn = jax.jit(step, in_shardings=(psh, bsh, csh, rep),
-                     out_shardings=(None, csh), donate_argnums=(2,))
+                     out_shardings=out_sh, donate_argnums=(2,))
         lowered = fn.lower(psp, bs, csp,
                            jax.ShapeDtypeStruct((), jnp.int32))
 

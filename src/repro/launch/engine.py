@@ -252,6 +252,19 @@ def _copy_pool_pages(cache, pairs: List[Tuple[int, int]]):
     return jax.tree.unflatten(treedef, out)
 
 
+def _to_host(tokens, counts, sp):
+    """The step's next tokens on the host.  A MoE stack's step also gives
+    its expert counts (``models.moe``): they come back in the same
+    transfer and become the span's ``picks_here`` and ``expert_rows``."""
+    import jax
+
+    if not counts:
+        return np.asarray(tokens)
+    tokens, (picks, rows) = jax.device_get((tokens, counts[0]))
+    sp.attrs.update(picks_here=int(picks), expert_rows=int(rows))
+    return tokens
+
+
 # ---------------------------------------------------------------------------
 # Requests and per-slot records
 # ---------------------------------------------------------------------------
@@ -584,7 +597,7 @@ class ServingEngine:
             width = -(-len(tail) // self.ps) * self.ps if self.ragged \
                 else len(tail)
             with spans.span("engine.prefill", slots=1, width=width,
-                            tokens=len(tail)):
+                            tokens=len(tail)) as sp:
                 if cow_pairs:
                     # every copy of the round, before its first prefill
                     self.cache = _copy_pool_pages(self.cache, cow_pairs)
@@ -602,10 +615,10 @@ class ServingEngine:
                     # every prefill, so evict-replay stays byte-identical
                     per_row += (jnp.asarray([C], jnp.int32),)
                 view = cache_slot_view(self.cache, self.B, b)
-                logits, view = self.prefill(self.params, batch, view,
-                                            *per_row)
+                logits, view, *counts = self.prefill(self.params, batch,
+                                                     view, *per_row)
                 self.cache = cache_slot_merge(self.cache, view, self.B, b)
-                tok = int(jnp.argmax(logits[0, -1]))
+                tok = int(_to_host(jnp.argmax(logits[0, -1]), counts, sp))
             rec = self.slots[b]
             if self.prefix_cache:
                 # the round's freshly prefilled full pages become cache
@@ -704,11 +717,12 @@ class ServingEngine:
                 self._ensure_pages()
             if all(s is None for s in self.slots):
                 return []                        # everything got evicted
-            with spans.span("engine.decode"):
-                logits, self.cache = self.decode(
+            with spans.span("engine.decode") as sp:
+                logits, self.cache, *counts = self.decode(
                     self.params, {"tokens": jnp.asarray(self.toks)},
                     self.cache, jnp.asarray(self.pos, jnp.int32))
-                nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+                nxt = _to_host(jnp.argmax(logits[:, -1], axis=-1), counts,
+                               sp)
             self.decode_steps += 1
             finished: List[int] = []
             for b in range(self.B):

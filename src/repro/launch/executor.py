@@ -132,14 +132,15 @@ def run_lockstep(cfg, ctx, params, sv: ServeSpec) -> int:
     cache = init_cache(cfg, B, max_len, src_len=src_len)
 
     t0 = time.perf_counter()
-    logits, cache = prefill(params, batch, cache)
+    logits, cache = prefill(params, batch, cache)[:2]
     tok = jnp.argmax(logits[:, -1], axis=-1)[:, None]
     t_prefill = time.perf_counter() - t0
 
     out = [tok]
     t0 = time.perf_counter()
     for t in range(P, P + G - 1):
-        logits, cache = decode(params, {"tokens": tok}, cache, jnp.int32(t))
+        logits, cache = decode(params, {"tokens": tok}, cache,
+                               jnp.int32(t))[:2]
         tok = jnp.argmax(logits[:, -1], axis=-1)[:, None]
         out.append(tok)
     jax.block_until_ready(tok)

@@ -39,9 +39,11 @@ NAMES = (
     "engine.match",      #   its slot loop: prefix match, reservation, pages
     "engine.tables",     #   each page-table upload to the device
     "engine.prefill",    #   first device dispatch -> next tokens on the host
+                         #   (a MoE stack's also: picks_here, expert_rows)
     "engine.step",       # one decode step
     "engine.grow",       #   page growth before the decode dispatch
     "engine.decode",     #   decode dispatch -> next tokens on the host
+                         #   (picks_here, expert_rows as for prefill)
     "engine.finish",     #   one finished slot
     "engine.snapshot",   # the engine state pulled to the host
 )

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import LOCAL_ATTN, ModelConfig
-from repro.models.layers import Ctx, apply_rope, rms_norm, softcap
+from repro.models.layers import Ctx, apply_rope, rms_norm, softcap, yarn_mscale
 
 Cache = Dict[str, jax.Array]
 NEG_INF = -2.0e38
@@ -235,9 +235,85 @@ def decode_attention_paged(
                                 logit_cap=logit_cap)
 
 
+#: MLA prefill block sizes: the transient score block is
+#: (B, MLA_Q_BLOCK, H, MLA_K_BLOCK) float32, whatever the prompt's length
+MLA_Q_BLOCK = 256
+MLA_K_BLOCK = 512
+
+
+def _mla_blocked(q_nope, q_rope, w_kc, w_vc, key_block, n_kblocks: int,
+                 k_block: int, pos_q, lengths, *, scale: float,
+                 q_block: int) -> jax.Array:
+    """Absorbed MLA attention in blocks: ``lax.map`` over query blocks,
+    each an online softmax over the key blocks up to its last visible
+    position (a dynamic trip count: causal blocks are skipped, not
+    masked).  Per query block the W_kc-absorbed queries, the latent
+    context and its W_vc read-out are made and dropped, so the transient
+    is (B, q_block, H, k_block) and no tensor grows with S0·T.
+
+    ``key_block(j)`` returns key block ``j``'s latents ``(B, kb, lora)``,
+    rotated rope keys ``(B, kb, rd)`` and absolute positions ``(B, kb)``
+    (-1 = no key).  A key is live for query ``s`` of row ``b`` iff its
+    position is ``<= pos_q[b, s]`` and ``s < lengths[b]``; rows with no
+    live key (inactive slots, padding) return zeros.  Returns
+    (B, S0, H, vd) in ``q_nope``'s dtype."""
+    B, S0, H, _ = q_nope.shape
+    dt = q_nope.dtype
+    qb = min(q_block, S0)
+    nq = -(-S0 // qb)
+    pad = nq * qb - S0
+    live = jnp.arange(S0, dtype=jnp.int32)[None, :] \
+        < lengths.astype(jnp.int32)[:, None]                       # (B, S0)
+    pq = jnp.where(live, pos_q.astype(jnp.int32), -1)
+
+    def blocks(x, fill):
+        x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2),
+                    constant_values=fill)
+        return jnp.moveaxis(x.reshape(B, nq, qb, *x.shape[2:]), 1, 0)
+
+    def one_block(args):
+        qn, qr, pqb = args                                # (B, qb, ...)
+        q_eff = jnp.einsum("bshe,lhe->bshl", qn, w_kc.astype(dt))
+        q_eff = q_eff.astype(jnp.float32)
+        qr = qr.astype(jnp.float32)
+        n_k = jnp.minimum(jnp.max(pqb) // k_block + 1, n_kblocks)
+
+        def step(j, carry):
+            m, l, acc = carry
+            cb, rb, pk = key_block(j)
+            cb = cb.astype(jnp.float32)
+            s = jnp.einsum("bshl,btl->bsht", q_eff, cb)
+            s = s + jnp.einsum("bshr,btr->bsht", qr, rb.astype(jnp.float32))
+            s = s * scale
+            vm = ((pk[:, None, :] >= 0)
+                  & (pk[:, None, :] <= pqb[:, :, None]))[:, :, None, :]
+            s = jnp.where(vm, s, NEG_INF)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            # explicit p-masking: a dead row's exp(NEG_INF - NEG_INF) == 1
+            p = jnp.where(vm, jnp.exp(s - m_new[..., None]), 0.0)
+            corr = jnp.exp(m - m_new)
+            l = l * corr + p.sum(axis=-1)
+            acc = acc * corr[..., None] + jnp.einsum("bsht,btl->bshl", p, cb)
+            return m_new, l, acc
+
+        init = (jnp.full((B, qb, H), NEG_INF, jnp.float32),
+                jnp.zeros((B, qb, H), jnp.float32),
+                jnp.zeros((B, qb, H, q_eff.shape[-1]), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, n_k, step, init)
+        ctx_lat = (acc / jnp.maximum(l, 1e-37)[..., None]).astype(dt)
+        return jnp.einsum("bshl,lhe->bshe", ctx_lat, w_vc.astype(dt))
+
+    out = jax.lax.map(one_block, (blocks(q_nope, 0), blocks(q_rope, 0),
+                                  blocks(pq, -1)))         # (nq, B, qb, H, vd)
+    out = jnp.moveaxis(out, 0, 1).reshape(B, nq * qb, H, -1)
+    return out[:, :S0]
+
+
 def mla_prefill_attention_paged(
-    q_eff: jax.Array,        # (B, S0, H, lora) — W_kc-absorbed queries
+    q_nope: jax.Array,       # (B, S0, H, nope)
     q_rope: jax.Array,       # (B, S0, H, rd)   — rotated rope queries
+    w_kc: jax.Array,         # (lora, H, nope)  — absorbed into the queries
+    w_vc: jax.Array,         # (lora, H, vd)    — reads the latent context
     ckv_pages: jax.Array,    # (P, page_size, lora) shared latent pool
     krope_pages: jax.Array,  # (P, page_size, rd)
     page_table: jax.Array,   # (B, pages_per_seq) int32; -1 = unallocated
@@ -245,44 +321,74 @@ def mla_prefill_attention_paged(
     lengths: jax.Array,      # (B,) valid chunk tokens; 0 = inactive row
     *,
     scale: float,
+    q_block: int = MLA_Q_BLOCK,
+    k_block: int = MLA_K_BLOCK,
 ) -> jax.Array:
-    """Chunked/ragged MLA prefill over the latent page table.
+    """Chunked/ragged MLA prefill over the latent page table, in blocks.
 
     The latent cache is MQA-shaped — ONE shared latent "kv head" serves
     all H query heads; scores are ``q_eff·ckv + q_rope·krope`` and the
-    value read is the latent itself (``W_vc`` is applied outside).  Same
-    write-then-read contract as :func:`prefill_attention_paged`: the
-    chunk's latents were already scattered into the pool, so one masked
-    walk covers the cached prefix and within-chunk causality.  Returns
-    the latent context (B, S0, H, lora)."""
-    B, S0, H, lora = q_eff.shape
+    value read is the latent itself, turned into per-head values by
+    ``W_vc``.  Same write-then-read contract as
+    :func:`prefill_attention_paged`: the chunk's latents were already
+    scattered into the pool, so one masked walk covers the cached prefix
+    and within-chunk causality.  Key blocks are whole pages gathered from
+    the table (``k_block`` rounded to pages).  Returns (B, S0, H, vd)."""
+    B = q_nope.shape[0]
     ps = ckv_pages.shape[1]
-    pps = page_table.shape[1]
-    T = pps * ps
-    cb = jnp.take(ckv_pages, page_table, axis=0, mode="fill",
-                  fill_value=0)                      # (B, pps, ps, lora)
-    rb = jnp.take(krope_pages, page_table, axis=0, mode="fill", fill_value=0)
-    cb = cb.reshape(B, T, lora)
-    rb = rb.reshape(B, T, rb.shape[-1])
-    pos_k = jnp.where(jnp.repeat(page_table >= 0, ps, axis=1),
-                      jnp.arange(T, dtype=jnp.int32)[None, :], -1)   # (B, T)
-    s = jnp.einsum("bshl,btl->bsht", q_eff.astype(jnp.float32),
-                   cb.astype(jnp.float32))
-    s = s + jnp.einsum("bshr,btr->bsht", q_rope.astype(jnp.float32),
-                       rb.astype(jnp.float32))
-    s = s * scale
-    valid = (pos_k[:, None, :] >= 0) \
-        & (pos_k[:, None, :] <= pos_q[:, :, None]) \
-        & (jnp.arange(S0, dtype=jnp.int32)[None, :, None]
-           < lengths.astype(jnp.int32)[:, None, None])               # (B,S0,T)
-    vm = valid[:, :, None, :]
-    s = jnp.where(vm, s, NEG_INF)
-    m = s.max(axis=-1, keepdims=True)
-    # explicit p-masking: fully-dead rows would see exp(NEG_INF-NEG_INF)==1
-    p = jnp.where(vm, jnp.exp(s - m), 0.0)
-    l = p.sum(axis=-1)
-    ctx_lat = jnp.einsum("bsht,btl->bshl", p, cb.astype(jnp.float32))
-    return (ctx_lat / jnp.maximum(l, 1e-37)[..., None]).astype(q_eff.dtype)
+    per = max(1, k_block // ps)                  # pages per key block
+    n_k = -(-page_table.shape[1] // per)
+    table = jnp.pad(page_table, [(0, 0), (0, n_k * per
+                                          - page_table.shape[1])],
+                    constant_values=-1)
+    kb = per * ps
+
+    def key_block(j):
+        t = jax.lax.dynamic_slice_in_dim(table, j * per, per, axis=1)
+        cb = jnp.take(ckv_pages, t, axis=0, mode="fill", fill_value=0)
+        rb = jnp.take(krope_pages, t, axis=0, mode="fill", fill_value=0)
+        pk = jnp.where(jnp.repeat(t >= 0, ps, axis=1),
+                       j * kb + jnp.arange(kb, dtype=jnp.int32)[None, :], -1)
+        return cb.reshape(B, kb, -1), rb.reshape(B, kb, -1), pk
+
+    return _mla_blocked(q_nope, q_rope, w_kc, w_vc, key_block, n_k, kb,
+                        pos_q, lengths, scale=scale, q_block=q_block)
+
+
+def mla_prefill_attention_fresh(
+    q_nope: jax.Array,       # (B, S0, H, nope)
+    q_rope: jax.Array,       # (B, S0, H, rd)
+    w_kc: jax.Array,
+    w_vc: jax.Array,
+    ckv: jax.Array,          # (B, S0, lora) the chunk's own latents
+    krope: jax.Array,        # (B, S0, rd)   rotated
+    lengths: jax.Array,      # (B,) valid tokens
+    *,
+    scale: float,
+    q_block: int = MLA_Q_BLOCK,
+    k_block: int = MLA_K_BLOCK,
+) -> jax.Array:
+    """MLA prefill of a chunk opening at position 0 against its own fresh
+    latents, in blocks; causality isolates each row's last valid query
+    from the ragged padding keys (they sit at later positions).  Returns
+    (B, S0, H, vd)."""
+    B, S0 = ckv.shape[:2]
+    kb = min(k_block, S0)
+    n_k = -(-S0 // kb)
+    pad = [(0, 0), (0, n_k * kb - S0), (0, 0)]
+    ckv, krope = jnp.pad(ckv, pad), jnp.pad(krope, pad)
+    pos = jnp.arange(n_k * kb, dtype=jnp.int32)
+    pos = jnp.where(pos < S0, pos, -1)
+
+    def key_block(j):
+        return (jax.lax.dynamic_slice_in_dim(ckv, j * kb, kb, axis=1),
+                jax.lax.dynamic_slice_in_dim(krope, j * kb, kb, axis=1),
+                jnp.broadcast_to(jax.lax.dynamic_slice_in_dim(
+                    pos, j * kb, kb), (B, kb)))
+
+    pos_q = jnp.broadcast_to(jnp.arange(S0, dtype=jnp.int32), (B, S0))
+    return _mla_blocked(q_nope, q_rope, w_kc, w_vc, key_block, n_k, kb,
+                        pos_q, lengths, scale=scale, q_block=q_block)
 
 
 def mla_decode_attention_paged(
@@ -727,8 +833,18 @@ def _mla_q(cfg: ModelConfig, p, x, pos) -> Tuple[jax.Array, jax.Array]:
     else:
         q = jnp.einsum("bsd,dhk->bshk", x, p["q"].astype(x.dtype))
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = apply_rope(q_rope, pos, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, pos, cfg.rope_theta, cfg.rope_yarn)
     return q_nope, q_rope
+
+
+def mla_scale(cfg: ModelConfig) -> float:
+    """The MLA softmax scale: (nope+rope)^-1/2, times YaRN's mscale^2
+    where the rope is YaRN-scaled (DeepSeek-V2's ``mscale_all_dim``)."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.rope_yarn is None:
+        return scale
+    m = yarn_mscale(cfg.rope_scaling_factor, cfg.yarn_mscale)
+    return scale * m * m
 
 
 def mla_attention(
@@ -746,7 +862,8 @@ def mla_attention(
     H = cfg.num_heads
     nope, rd, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     lora = cfg.kv_lora_rank
-    scale = (nope + rd) ** -0.5
+    scale = mla_scale(cfg)
+    yarn = cfg.rope_yarn
     kv_b = p["kv_b"]                                      # (lora, H, nope+vd)
     w_kc = kv_b[..., :nope]                               # (lora, H, nope)
     w_vc = kv_b[..., nope:]                               # (lora, H, vd)
@@ -760,47 +877,33 @@ def mla_attention(
         # ---- paged latent prefill.  Writes always scatter the chunk's
         # latents into the pool (length-masked per row).  The attention
         # read splits like the GQA path: lockstep/ragged chunks opening at
-        # position 0 score against the FRESH fp32 latents (matching the
-        # dense oracle bit-for-bit in math — the pool stores the cache
-        # dtype, and rounding keys through it would cost ~1e-3 vs dense),
-        # while chunked prefix prefill (2-D pos) must read the pool — the
-        # cached prefix only exists there, and both sides of a chunk split
-        # see identical pool bytes, keeping replay bit-exact.
+        # position 0 score against the FRESH latents (the pool stores the
+        # cache dtype, and rounding keys through it would cost ~1e-3 vs the
+        # dense oracle at float32), while chunked prefix prefill (2-D pos)
+        # must read the pool — the cached prefix only exists there, and
+        # both sides of a chunk split see identical pool bytes, keeping
+        # replay bit-exact.  Both read in blocks (``_mla_blocked``).
         S0 = x.shape[1]
         pos_q = pos if pos.ndim == 2 else \
             jnp.broadcast_to(pos[None, :], (B, S0))       # (B, S0) absolute
         lens = jnp.full((B,), S0, jnp.int32) if lengths is None \
             else lengths.astype(jnp.int32)
         q_nope, q_rope = _mla_q(cfg, p, x, pos)
-        k_rope = apply_rope(k_rope, pos, cfg.rope_theta)
+        k_rope = apply_rope(k_rope, pos, cfg.rope_theta, yarn)
         new_cache = _write_prefill_latent_paged(
             cache, ckv, k_rope[:, :, 0], lens, pos_q)
-        q_eff = jnp.einsum("bshe,lhe->bshl", q_nope, w_kc)
         if pos.ndim == 2:
-            ctx_lat = mla_prefill_attention_paged(
-                q_eff, q_rope, new_cache["ckv_pages"],
+            out = mla_prefill_attention_paged(
+                q_nope, q_rope, w_kc, w_vc, new_cache["ckv_pages"],
                 new_cache["krope_pages"], new_cache["page_table"],
                 pos_q, lens, scale=scale)
         else:
-            # fresh-latent absorbed walk; causality isolates each row's
-            # last valid query from the ragged padding keys (they sit at
-            # later positions), exactly like the dense flash path
-            s = jnp.einsum("bshl,btl->bsht", q_eff.astype(jnp.float32),
-                           ckv.astype(jnp.float32))
-            s = s + jnp.einsum("bshr,btr->bsht", q_rope.astype(jnp.float32),
-                               k_rope[:, :, 0].astype(jnp.float32))
-            s = s * scale
-            causal = (jnp.arange(S0)[None, :, None]
-                      >= jnp.arange(S0)[None, None, :])[:, :, None, :]
-            s = jnp.where(causal, s, NEG_INF)
-            pr = jax.nn.softmax(s, axis=-1)
-            ctx_lat = jnp.einsum("bsht,btl->bshl", pr,
-                                 ckv.astype(jnp.float32)).astype(x.dtype)
-        out = jnp.einsum("bshl,lhe->bshe", ctx_lat.astype(x.dtype),
-                         w_vc.astype(x.dtype))
+            out = mla_prefill_attention_fresh(
+                q_nope, q_rope, w_kc, w_vc, ckv, k_rope[:, :, 0], lens,
+                scale=scale)
     elif mode == "full":
         q_nope, q_rope = _mla_q(cfg, p, x, pos)
-        k_rope = apply_rope(k_rope, pos, cfg.rope_theta)
+        k_rope = apply_rope(k_rope, pos, cfg.rope_theta, yarn)
         kv = jnp.einsum("bsl,lhe->bshe", ckv, kv_b.astype(ckv.dtype))  # expand
         k_nope, v = kv[..., :nope], kv[..., nope:]
         # fold the shared rope head into per-head keys
@@ -831,7 +934,7 @@ def mla_attention(
             jnp.reshape(jnp.asarray(pos, jnp.int32), (-1,)), (B,))
         pos_r = jnp.reshape(posb, (-1, 1))                # (B, 1) for rope
         q_nope, q_rope = _mla_q(cfg, p, x, pos_r)
-        k_rope = apply_rope(k_rope, pos_r, cfg.rope_theta)
+        k_rope = apply_rope(k_rope, pos_r, cfg.rope_theta, yarn)
         new_cache = _update_decode_latent_paged(
             cache, ckv[:, 0], k_rope[:, 0, 0], posb)
         cp_pages, rp_pages = new_cache["ckv_pages"], new_cache["krope_pages"]
@@ -856,7 +959,8 @@ def mla_attention(
             "per-sequence MLA decode positions need the paged latent " \
             "cache (cache_layout='paged'); the dense cache is lockstep-only"
         q_nope, q_rope = _mla_q(cfg, p, x, pos[None] if pos.ndim == 0 else pos)
-        k_rope = apply_rope(k_rope, jnp.reshape(pos, (1,)), cfg.rope_theta)
+        k_rope = apply_rope(k_rope, jnp.reshape(pos, (1,)), cfg.rope_theta,
+                            yarn)
         c_new = jax.lax.dynamic_update_slice_in_dim(
             cache["ckv"], ckv.astype(cache["ckv"].dtype), pos, axis=1)
         r_new = jax.lax.dynamic_update_slice_in_dim(

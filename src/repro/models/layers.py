@@ -1,6 +1,7 @@
 """Shared numeric primitives + the forward-pass context object."""
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -69,17 +70,47 @@ def softcap(x: jax.Array, cap: float) -> jax.Array:
 # ---------------------------------------------------------------------------
 # RoPE
 # ---------------------------------------------------------------------------
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
-    """(head_dim//2,) inverse frequencies, fp32."""
+def rope_freqs(head_dim: int, theta: float, yarn=None) -> jax.Array:
+    """(head_dim//2,) inverse frequencies, fp32.  ``yarn`` is
+    ``(factor, original_max_pos, beta_fast, beta_slow)``: YaRN blends each
+    frequency with its ``factor``-interpolated value along the linear ramp
+    between the correction dims of ``beta_fast`` and ``beta_slow``
+    rotations over the original context (DeepSeek-V2's rope_scaling)."""
     exponent = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
-    return 1.0 / (theta ** exponent)
+    freqs = 1.0 / (theta ** exponent)
+    if yarn is None:
+        return freqs
+    factor, orig, fast, slow = yarn
+    low, high = yarn_correction_range(head_dim, theta, orig, fast, slow)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return freqs / factor * ramp + freqs * (1.0 - ramp)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def yarn_correction_range(dim: int, theta: float, orig: int, fast: float,
+                          slow: float) -> tuple:
+    """(low, high) rotary dims between which YaRN ramps from the original
+    frequencies to the interpolated ones."""
+    def dim_of(rot):
+        return dim * math.log(orig / (rot * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    return (max(math.floor(dim_of(fast)), 0),
+            min(math.ceil(dim_of(slow)), dim - 1))
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term, 0.1·m·ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn=None) -> jax.Array:
     """Rotate ``x (..., seq, heads, head_dim)`` at absolute ``positions (seq,)``
-    (or broadcastable ``(..., seq)``)."""
+    (or broadcastable ``(..., seq)``); ``yarn`` as in :func:`rope_freqs`."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    freqs = rope_freqs(hd, theta, yarn)                 # (hd/2,)
     ang = positions.astype(jnp.float32)[..., None] * freqs   # (..., seq, hd/2)
     ang = ang[..., None, :]                             # broadcast over heads
     sin, cos = jnp.sin(ang), jnp.cos(ang)

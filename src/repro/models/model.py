@@ -136,7 +136,9 @@ def apply_block(
         if "moe" in p and not dense_only:
             with jax.named_scope("moe"):
                 y, aux = moe_ffn(cfg, p["moe"], x, ctx,
-                                 dropless=mode != "train")
+                                 dropless=mode != "train",
+                                 valid=_counted(mode, pos, lengths,
+                                                x.shape[1]))
         else:
             with jax.named_scope("ffn"):
                 y = dense_ffn(p["ffn"], x, cfg.act, ctx)
@@ -144,6 +146,25 @@ def apply_block(
     h = h + _post("post_ffn_norm", y)
     h = ctx.constrain(h, ("batch", "resid_seq", "embed_act"))
     return h, new_cache, aux
+
+
+def _counted(mode: str, pos: jax.Array, lengths: Optional[jax.Array],
+             S: int) -> Optional[jax.Array]:
+    """The tokens a serving step's expert counts take in, (B|1, S|1):
+    decode rows with a position (inactive slots carry -1), prefill rows'
+    first ``lengths`` tokens; None = all."""
+    if mode == "decode":
+        return jnp.reshape(pos >= 0, (-1, 1))
+    if lengths is not None:
+        return jnp.arange(S)[None, :] < lengths[:, None]
+    return None
+
+
+def _aux_init(cfg: ModelConfig, mode: str) -> jax.Array:
+    """The aux accumulator: the load-balancing loss in training; a MoE
+    stack's serving steps sum the layers' (picks_here, expert_rows)."""
+    return jnp.zeros((2,) if cfg.is_moe and mode != "train" else (),
+                     jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -176,7 +197,7 @@ def run_stack(
     lengths: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[Tree], jax.Array]:
     pat = cfg.block_pattern
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = _aux_init(cfg, mode)
     new_cache: Tree = {} if cache is not None else None
 
     def run_layer(i_kind, p, h, c):
@@ -310,11 +331,15 @@ def forward(
     lengths: Optional[jax.Array] = None,  # ragged prefill: (B,) prompt lens
     starts: Optional[jax.Array] = None,   # chunked prefill: (B,) first positions
 ) -> Tuple[jax.Array, Optional[Tree], jax.Array]:
-    """Returns (logits, new_cache, aux_loss).
+    """Returns (logits, new_cache, aux).
 
     train:   logits (B, S, V) for every position
     prefill: logits (B, 1, V) for the last position + filled cache
     decode:  logits (B, 1, V) + updated cache
+
+    ``aux`` is the load-balancing loss in training; in prefill and decode
+    a MoE stack gives ``(picks_here, expert_rows)`` summed over its MoE
+    layers (``models.moe``), other stacks zero.
 
     ``lengths`` makes prefill *ragged*: the (B, S0) token batch is padded
     to the round's max prompt length, row ``b``'s true prompt is its first

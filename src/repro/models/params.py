@@ -136,12 +136,14 @@ def _dense_ffn_params(cfg: ModelConfig, d_ff: Optional[int] = None) -> Tree:
 
 
 def _moe_ffn_params(cfg: ModelConfig) -> Tree:
-    D, E, Fe = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
+    """The router scores all E experts; the layer holds the weights of
+    ``cfg.experts_held`` of them."""
+    D, E, Eh, Fe = cfg.d_model, cfg.num_experts, cfg.experts_held, cfg.moe_d_ff
     p: Tree = {
         "router": ParamAb((D, E), ("embed", "experts"), "normal:0.02"),
-        "we_g": ParamAb((E, D, Fe), ("experts", "embed", "expert_ffn")),
-        "we_u": ParamAb((E, D, Fe), ("experts", "embed", "expert_ffn")),
-        "we_d": ParamAb((E, Fe, D), ("experts", "expert_ffn", "embed")),
+        "we_g": ParamAb((Eh, D, Fe), ("experts", "embed", "expert_ffn")),
+        "we_u": ParamAb((Eh, D, Fe), ("experts", "embed", "expert_ffn")),
+        "we_d": ParamAb((Eh, Fe, D), ("experts", "expert_ffn", "embed")),
     }
     if cfg.num_shared_experts:
         Fs = Fe * cfg.num_shared_experts

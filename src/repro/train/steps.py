@@ -158,23 +158,29 @@ def make_prefill_step(cfg: ModelConfig, ctx: Ctx):
     writes (length-0 rows untouched — see models.model.forward).
     ``starts`` (B,) additionally makes it *chunked* (prefix caching): row
     ``b``'s tokens are the uncached tail of its prompt, opening at
-    absolute position ``starts[b]``."""
+    absolute position ``starts[b]``.  A MoE stack's step also returns
+    its expert counts (float32 ``(picks_here, expert_rows)``, see
+    ``models.moe``)."""
     @jax.named_scope("prefill")
     def prefill_step(params, batch, cache, lengths=None, starts=None):
-        logits, new_cache, _ = forward(cfg, params, batch, ctx,
-                                       mode="prefill", cache=cache,
-                                       lengths=lengths, starts=starts)
-        return logits, new_cache
+        logits, new_cache, counts = forward(cfg, params, batch, ctx,
+                                            mode="prefill", cache=cache,
+                                            lengths=lengths, starts=starts)
+        return (logits, new_cache, counts) if cfg.is_moe \
+            else (logits, new_cache)
     return prefill_step
 
 
 def make_decode_step(cfg: ModelConfig, ctx: Ctx):
-    """(params, tokens (B,1), cache, pos scalar|(B,)) -> (logits, cache)."""
+    """(params, tokens (B,1), cache, pos scalar|(B,)) -> (logits, cache),
+    and a MoE stack's expert counts after them."""
     @jax.named_scope("decode")
     def decode_step(params, batch, cache, pos):
-        logits, new_cache, _ = forward(cfg, params, batch, ctx,
-                                       mode="decode", cache=cache, pos=pos)
-        return logits, new_cache
+        logits, new_cache, counts = forward(cfg, params, batch, ctx,
+                                            mode="decode", cache=cache,
+                                            pos=pos)
+        return (logits, new_cache, counts) if cfg.is_moe \
+            else (logits, new_cache)
     return decode_step
 
 

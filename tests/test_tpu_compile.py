@@ -94,6 +94,27 @@ def test_mla_paged_decode_deepseek_v2_widths(one_chip):
              ((B, pps), jnp.int32), ((B,), jnp.int32))
 
 
+def test_mla_blocked_prefill_deepseek_v2_widths(one_chip):
+    """The blocked MLA prefill of one 8,192-token row over the doc-qa
+    cell's latent pool (544 pages of 256 tokens): its temporaries, beside
+    the cut's 7.63 GB of bfloat16 weights and its own arguments, fit one
+    16 GB chip."""
+    from repro.models.attention import mla_prefill_attention_paged
+    S0, H, lora, rd, nope, ps, pool, pps = 8192, 128, 512, 64, 128, 256, \
+        544, 34
+    bf = jnp.bfloat16
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in [
+        ((1, S0, H, nope), bf), ((1, S0, H, rd), bf), ((lora, H, nope), bf),
+        ((lora, H, nope), bf), ((pool, ps, lora), bf), ((pool, ps, rd), bf),
+        ((1, pps), jnp.int32), ((1, S0), jnp.int32), ((1,), jnp.int32)]]
+    fn = lambda *a: mla_prefill_attention_paged(*a, scale=0.11472)
+    m = jax.jit(fn).lower(*args).compile().memory_analysis()
+    weights = 7.63e9
+    assert m.temp_size_in_bytes + m.argument_size_in_bytes + weights < 16e9
+    # no (S0, H, T) float32 score tensor: that alone would be 36 GB
+    assert m.temp_size_in_bytes < 2e9, m.temp_size_in_bytes
+
+
 def test_rglru_scan_recurrentgemma_widths(one_chip):
     from repro.kernels.rglru_scan import rglru_scan
     # recurrentgemma-9b: rnn_width 4096

@@ -105,14 +105,17 @@ def measured_rows(smoke: bool):
     P = B * pps                                  # worst-case pool
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(B, 1, H, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, K, ps, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, K, ps, hd)), jnp.float32)
+    # a one-layer stack of the token-major pool, read at layer 0
+    kp = jnp.asarray(rng.normal(size=(1, P, ps, K * hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(1, P, ps, K * hd)), jnp.float32)
+    layer = jnp.int32(0)
     scale = hd ** -0.5
 
-    ref = jax.jit(functools.partial(decode_attention_paged, scale=scale))
+    ref = jax.jit(functools.partial(decode_attention_paged, layer=layer,
+                                    scale=scale))
     scan = jax.jit(functools.partial(
         lambda q, k, v, t, p, scale: paged_decode_jnp(
-            q.reshape(B, K, H // K, hd), k, v, t, p,
+            q.reshape(B, K, H // K, hd), k, v, t, p, layer,
             scale=scale).reshape(B, 1, H, hd), scale=scale))
 
     shape_meta = {"B": B, "H": H, "K": K, "hd": hd, "page_size": ps,
@@ -130,7 +133,7 @@ def measured_rows(smoke: bool):
         t_ref = _time_it(ref, q, kp, vp, table_j, pos, iters=iters)
         t_scan = _time_it(scan, q, kp, vp, table_j, pos, iters=iters)
         # one interpret-mode kernel step, checked against the reference
-        out_k = paged_decode_bhd(q, kp, vp, table_j, pos, scale=scale)
+        out_k = paged_decode_bhd(q, kp, vp, table_j, pos, layer, scale=scale)
         out_r = ref(q, kp, vp, table_j, pos)
         kernel_err = max(kernel_err, float(jnp.abs(out_k - out_r).max()))
         token_bytes = 2 * K * hd * 4                     # K+V, fp32
@@ -168,11 +171,13 @@ def mla_measured_rows(smoke: bool):
     P = B * pps
     rng = np.random.default_rng(1)
     q = jnp.asarray(rng.normal(size=(B, H, lora + rd)), jnp.float32)
-    ckv = jnp.asarray(rng.normal(size=(P, ps, lora)), jnp.float32)
-    krope = jnp.asarray(rng.normal(size=(P, ps, rd)), jnp.float32)
+    ckv = jnp.asarray(rng.normal(size=(1, P, ps, lora)), jnp.float32)
+    krope = jnp.asarray(rng.normal(size=(1, P, ps, rd)), jnp.float32)
+    layer = jnp.int32(0)
     scale = (lora + rd) ** -0.5
 
-    scan = jax.jit(functools.partial(mla_paged_decode_jnp, scale=scale))
+    scan = jax.jit(functools.partial(mla_paged_decode_jnp, layer=layer,
+                                     scale=scale))
     shape_meta = {"B": B, "H": H, "kv_lora_rank": lora, "rope_dim": rd,
                   "page_size": ps, "pages_per_seq": pps, "pool_pages": P,
                   "iters": iters, "backend": jax.default_backend()}
@@ -187,7 +192,7 @@ def mla_measured_rows(smoke: bool):
         pos = jnp.full((B,), live * ps - 1, jnp.int32)
         t_scan = _time_it(scan, q, ckv, krope, table_j, pos, iters=iters)
         out_k = mla_paged_decode_attention(q, ckv, krope, table_j, pos,
-                                           scale=scale, interpret=True)
+                                           layer, scale=scale, interpret=True)
         out_s = scan(q, ckv, krope, table_j, pos)
         kernel_err = max(kernel_err, float(jnp.abs(out_k - out_s).max()))
         steps.append({
@@ -219,8 +224,9 @@ def grouped_measured_rows(smoke: bool):
     P = B * pps
     rng = np.random.default_rng(2)
     q = jnp.asarray(rng.normal(size=(B, K, G, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(P, K, ps, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, K, ps, hd)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(1, P, ps, K * hd)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(1, P, ps, K * hd)), jnp.float32)
+    layer = jnp.int32(0)
     scale = hd ** -0.5
 
     live = max(pps // 2, 1)
@@ -230,16 +236,17 @@ def grouped_measured_rows(smoke: bool):
     table_j = jnp.asarray(table)
     pos = jnp.full((B,), live * ps - 1, jnp.int32)
 
-    scan = jax.jit(functools.partial(paged_decode_jnp, scale=scale))
+    scan = jax.jit(functools.partial(paged_decode_jnp, layer=layer,
+                                     scale=scale))
     t_scan = _time_it(scan, q, kp, vp, table_j, pos, iters=iters)
-    grp = paged_decode_attention(q, kp, vp, table_j, pos, scale=scale,
+    grp = paged_decode_attention(q, kp, vp, table_j, pos, layer, scale=scale,
                                  interpret=True, grouped=True)
-    ung = paged_decode_attention(q, kp, vp, table_j, pos, scale=scale,
+    ung = paged_decode_attention(q, kp, vp, table_j, pos, layer, scale=scale,
                                  interpret=True, grouped=False)
     out_s = scan(q, kp, vp, table_j, pos)
     return {
         "shape": {"B": B, "K": K, "G": G, "hd": hd, "page_size": ps,
-                  "pages_per_seq": pps, "head_tile": group_tile(K, G),
+                  "pages_per_seq": pps, "head_tile": group_tile(K, G, hd),
                   "iters": iters, "backend": jax.default_backend()},
         "scan_ms_per_step": round(t_scan * 1e3, 3),
         "grouped_vs_ungrouped_max_abs_err": float(

@@ -77,14 +77,15 @@ def train_spec(arch: str = "paper-overhead-100m", reduced: bool = False,
 
 
 def _layer0_pools(cache):
-    """Layer 0's (k_pages, v_pages) of a paged cache tree."""
+    """The first (k_pages, v_pages) of a paged cache tree, as layer stacks
+    (an unrolled layer's pool as a stack of one); the check reads layer 0."""
     import jax
     found = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
         key = getattr(path[-1], "key", None)
         if key in ("k_pages", "v_pages") and key not in found:
             stacked = any(getattr(p, "key", None) == "groups" for p in path)
-            found[key] = leaf[0] if stacked else leaf
+            found[key] = leaf if stacked else leaf[None]
     return found["k_pages"], found["v_pages"]
 
 
@@ -106,9 +107,10 @@ def decode_kernel_error(engine, seed: int = 0) -> float:
                       jnp.int32)
     pt = jnp.asarray(engine.host_table, jnp.int32)
     q = jax.random.normal(jax.random.key(seed), (B, 1, H, hd), kp.dtype)
-    ker = paged_decode_bhd(q, kp, vp, pt, pos, scale=hd ** -0.5)
+    layer = jnp.int32(0)
+    ker = paged_decode_bhd(q, kp, vp, pt, pos, layer, scale=hd ** -0.5)
     ref = paged_decode_jnp(q.reshape(B, K, H // K, hd), kp, vp, pt, pos,
-                           scale=hd ** -0.5).reshape(B, 1, H, hd)
+                           layer, scale=hd ** -0.5).reshape(B, 1, H, hd)
     act = np.asarray(pos) >= 0
     if not act.any():
         raise RuntimeError("no active slot to compare the kernel on")

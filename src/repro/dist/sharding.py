@@ -19,6 +19,11 @@ well-formedness guarantees are enforced at spec-construction time:
   dimension of the same spec is skipped (PartitionSpecs must not repeat
   mesh axes).
 
+A dimension made of whole units names its axis as ``(name, unit)``: the
+token-major page pool's row is ``K`` kv heads of ``head_dim`` values side
+by side, ``("kv_heads", head_dim)``, so a mesh axis shards it only when
+it divides the head count — never mid-head.
+
 ``DEFAULT_RULES`` is the production table; per-cell overrides (the §Perf
 hillclimbing knob, e.g. sequence-parallel residuals) go through
 :meth:`ShardingRules.override`.
@@ -33,7 +38,8 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-AxisName = Optional[str]
+#: a logical axis name, None (replicated), or (name, unit) — see above
+AxisName = Union[None, str, Tuple[str, int]]
 MeshAxes = Tuple[str, ...]
 
 
@@ -151,7 +157,8 @@ def logical_to_spec(
 ) -> PartitionSpec:
     """Map per-dimension logical axis names to a valid ``PartitionSpec``.
 
-    ``None`` entries replicate that dimension.  Unknown logical names
+    ``None`` entries replicate that dimension; a ``(name, unit)`` entry
+    shards by whole units of ``unit`` elements.  Unknown logical names
     raise ``KeyError`` (a typo must fail loudly, not silently replicate).
     Trailing replicated dims are trimmed from the spec.
     """
@@ -163,6 +170,9 @@ def logical_to_spec(
         if name is None:
             entries.append(None)
             continue
+        if isinstance(name, tuple):
+            name, unit = name
+            dim //= unit
         chosen: list = []
         prod = 1
         for ax in rules.axes_for(name):

@@ -59,10 +59,11 @@ def flash_attention_bshd(
 @functools.partial(jax.jit, static_argnames=("scale", "logit_cap"))
 def paged_decode_bhd(
     q: jax.Array,            # (B, 1, H, hd) — one new token per sequence
-    k_pages: jax.Array,      # (P, K, ps, hd) shared physical pool
-    v_pages: jax.Array,      # (P, K, ps, hd)
+    k_pages: jax.Array,      # (L, P, ps, K·hd) token-major pool stack
+    v_pages: jax.Array,      # (L, P, ps, K·hd)
     page_table: jax.Array,   # (B, pps) int32; -1 = unallocated
     pos_q: jax.Array,        # (B,) int32; -1 = inactive slot
+    layer: jax.Array,        # () int32: the stack's layer to walk
     *,
     scale: float,
     logit_cap: float = 0.0,
@@ -70,21 +71,22 @@ def paged_decode_bhd(
     """Model-layout wrapper for the paged flash-decode kernel: regroup the
     q heads per kv head, run the kernel (interpret mode off-TPU), ungroup."""
     B, _, H, hd = q.shape
-    K = k_pages.shape[1]
+    K = k_pages.shape[-1] // hd
     qg = q.reshape(B, K, H // K, hd)
     out = _paged(qg, k_pages, v_pages, page_table.astype(jnp.int32),
-                 pos_q.astype(jnp.int32), scale=scale, logit_cap=logit_cap,
-                 interpret=_interpret())
+                 pos_q.astype(jnp.int32), layer, scale=scale,
+                 logit_cap=logit_cap, interpret=_interpret())
     return out.reshape(B, 1, H, hd)
 
 
 @functools.partial(jax.jit, static_argnames=("scale",))
 def mla_paged_decode_bhd(
     q_lat: jax.Array,        # (B, H, lora + rd) absorbed latent query
-    ckv_pages: jax.Array,    # (P, ps, lora) shared latent pool
-    krope_pages: jax.Array,  # (P, ps, rd) shared rope-key pool
+    ckv_pages: jax.Array,    # (L, P, ps, lora) latent pool stack
+    krope_pages: jax.Array,  # (L, P, ps, rd) rope-key pool stack
     page_table: jax.Array,   # (B, pps) int32; -1 = unallocated
     pos_q: jax.Array,        # (B,) int32; -1 = inactive slot
+    layer: jax.Array,        # () int32: the stack's layer to walk
     *,
     scale: float,
 ) -> jax.Array:
@@ -93,7 +95,7 @@ def mla_paged_decode_bhd(
     through W_vc (interpret mode off-TPU)."""
     return _mla_paged(q_lat, ckv_pages, krope_pages,
                       page_table.astype(jnp.int32), pos_q.astype(jnp.int32),
-                      scale=scale, interpret=_interpret())
+                      layer, scale=scale, interpret=_interpret())
 
 
 @jax.jit

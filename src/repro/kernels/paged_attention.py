@@ -1,8 +1,13 @@
 """Paged-attention decode (flash-decode over the page table).
 
 One new token per sequence attends a vLLM-style paged KV cache: a shared
-physical pool ``(P, K, page_size, hd)`` plus per-sequence page tables
-``(B, pages_per_seq)``.  The *reference* walk
+physical pool plus per-sequence page tables ``(B, pages_per_seq)``.  The
+pool is **token-major** and **stacked by layer**: ``(L, P, page_size,
+K·hd)``, one lane-dense row of every kv head's K (or V) per token, and
+the walk addresses layer ``layer`` of the stack.  A scanned layer group
+carries its whole stack through the scan and writes one row per token
+in place (``models.model.run_stack``); an unrolled layer passes its own
+pool as a stack of one.  The *reference* walk
 (``models.attention.decode_attention_paged``) gathers the table-bounded
 dense ``(B, pps·ps, K, hd)`` view every step — transient bandwidth scales
 with the table length, not with what the sequence actually holds.  Both
@@ -11,30 +16,32 @@ pages with a running online-softmax ``(m, l, acc)`` and never materialize
 the gathered view.
 
 * :func:`paged_decode_attention` — the Pallas TPU kernel.  Grid
-  ``(batch, kv_head, page)`` with the page dim minormost/sequential so the
-  running state lives in VMEM scratch; the page table and per-sequence
-  positions are **scalar-prefetched** so the BlockSpec index map can DMA
-  exactly the physical page each grid step needs.  Pages past the last
+  ``(batch, head_tile, page)`` with the page dim minormost/sequential so
+  the running state lives in VMEM scratch; the layer index, the page
+  table and the per-sequence positions are **scalar-prefetched** so the
+  BlockSpec index map can DMA exactly the ``(page_size, kt·hd)`` block of
+  the layer and physical page each grid step needs.  Pages past the last
   live one (slot ``t`` holds position ``t``, so pages ``> pos_q // ps``
-  are dead weight) re-map to the last live page — the block index repeats,
-  Pallas issues no new copy, and the tail of a mostly-empty table costs
-  nothing.  Runs in interpret mode off-TPU (CPU tests).
+  are dead weight) re-map to the last live page — the block index
+  repeats, Pallas issues no new copy, and the tail of a mostly-empty
+  table costs nothing.  Runs in interpret mode off-TPU (CPU tests).
 * :func:`paged_decode_jnp` — a ``lax.scan`` fallback with the same
   contract and the same O(pages) transient footprint, for serving without
-  ``use_pallas`` (the scan carries one ``(B, K, ps, hd)`` page gather per
+  ``use_pallas`` (the scan carries one ``(B, ps, K·hd)`` page gather per
   step instead of the whole table).
 
-When the query group G is small (GQA with few q heads per kv head), the
-per-kv-head grid issues a starving ``(G, hd) × (hd, ps)`` matmul per page;
-``grouped=True`` (the default) switches to a ``(batch, head_tile, page)``
-grid where a *tile* of ``kt`` kv heads' query groups hit the page in ONE
-MXU call — a block-diagonal masked ``(kt·G, hd) × (hd, kt·ps)`` score
-matmul (kt× redundant compute, traded for MXU occupancy).  ``kt`` is the
-largest divisor of K keeping ``kt·G`` within one MXU band (≤ 8 query
-rows), so G > 4 now runs grouped too: large groups simply tile one kv
-head at a time (kt = 1) with shared (m, l, acc) scratch per tile.
-Contract and numerics match the per-kv-head kernel, the scan fallback,
-and the ``decode_attention_paged`` oracle.
+A grid step reads a tile of ``kt`` kv heads: ``kt·hd`` columns of the
+page, which must fill whole 128-lane rows (or be the whole ``K·hd``
+row).  The tile's queries arrive **block-diagonal**, ``(kt·G, kt·hd)``
+with query head ``r``'s vector in its own kv head's columns and zeros
+elsewhere, so ONE ``(kt·G, kt·hd) × (kt·hd, ps)`` matmul gives every
+query head its own head's scores and the value matmul's own-head columns
+are read out after the walk.  ``grouped=True`` (the default) sizes the
+tile to fill one MXU band (≤ 8 query rows, :func:`group_tile`);
+``grouped=False`` takes the narrowest tile the lanes allow
+(:func:`lane_tile`: one kv head at ``hd >= 128``).  Contract and
+numerics match the scan fallback and the ``decode_attention_paged``
+oracle.
 
 MLA's latent cache gets the same treatment (:func:`mla_paged_layout` /
 :func:`mla_paged_decode_attention`): pages hold compressed latents +
@@ -50,13 +57,12 @@ Masking rules (shared by both, and by the reference):
   masked and the output row is **zero** (the reference's plain softmax
   returns a garbage average there instead; callers ignore those rows);
 * unallocated entries (``-1``) cost no bandwidth — the kernel's index
-  map re-maps them to an already-fetched live page (no new DMA) and the
-  fallback's ``take`` fills with zeros without reading the pool (the
-  clamp-to-page-0 of the old reference paid page 0's bandwidth for
-  every hole).
+  map re-maps them to an already-fetched live page (no new DMA); the
+  fallback's masked gather never lets them reach a score.
 
 Layouts: q ``(B, K, G, hd)`` (G = query heads per kv head), pool
-``(P, K, ps, hd)``, table ``(B, pps)`` int32, pos_q ``(B,)`` int32.
+``(L, P, ps, K·hd)``, table ``(B, pps)`` int32, pos_q ``(B,)`` int32,
+layer a scalar int32.
 """
 from __future__ import annotations
 
@@ -70,11 +76,17 @@ import jax.experimental.pallas.tpu as pltpu
 from repro.kernels.layout import KernelLayout, SpecDesc
 
 NEG_INF = -2.0e38
+LANES = 128
 
 
-def _decode_kernel(pt_ref, pq_ref, q_ref, k_ref, v_ref, o_ref,
+def _decode_kernel(lay_ref, pt_ref, pq_ref, q_ref, k_ref, v_ref, o_ref,
                    m_s, l_s, acc_s, *,
                    scale: float, logit_cap: float, ps: int, n_pages: int):
+    """Grid (batch, head_tile, page).  The tile's kt·G block-diagonal
+    query rows hit the page's (ps, kt·hd) token rows in ONE score matmul:
+    a query row's zeros outside its own kv head's columns leave exactly
+    its own head's scores.  The value matmul fills every head's columns
+    of each row; the caller keeps the row's own head's."""
     b = pl.program_id(0)
     i = pl.program_id(2)
 
@@ -90,9 +102,9 @@ def _decode_kernel(pt_ref, pq_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale        # (G, hd)
-        k = k_ref[0, 0].astype(jnp.float32)                # (ps, hd)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, ps)
+        q = q_ref[0, 0].astype(jnp.float32) * scale        # (ktG, kt·hd)
+        k = k_ref[0, 0].astype(jnp.float32)                # (ps, kt·hd)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (ktG, ps)
         if logit_cap:
             s = logit_cap * jnp.tanh(s / logit_cap)
         t = i * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -116,70 +128,8 @@ def _decode_kernel(pt_ref, pq_ref, q_ref, k_ref, v_ref, o_ref,
                        jnp.maximum(l_s[...], 1e-37)).astype(o_ref.dtype)
 
 
-def _decode_kernel_grouped(pt_ref, pq_ref, q_ref, k_ref, v_ref, o_ref,
-                           m_s, l_s, acc_s, *,
-                           scale: float, logit_cap: float, ps: int,
-                           n_pages: int):
-    """Grouped variant: grid (batch, head_tile, page) — a tile of ``kt``
-    kv heads' query groups (kt·G query heads) hits the page in ONE MXU
-    call.  The (kt·G, hd) × (hd, kt·ps) score matmul computes every
-    q-head × kv-head block *within the tile*; a block-diagonal mask
-    (query head r belongs to kv head r // G, key column c to kv head
-    c // ps) keeps only the matching ones.  The kt× redundant compute is
-    a win when G is small: the per-page matmul of the per-kv-head kernel
-    is a skinny (G, hd) × (hd, ps) that starves the MXU.  The tile size
-    comes from the BlockSpec (q block (1, kt, G, hd)) — the kernel body
-    is tile-size agnostic, so G > 4 runs the same code with kt = 1."""
-    b = pl.program_id(0)
-    i = pl.program_id(2)
-    _, kt, G, hd = q_ref.shape
-
-    @pl.when(i == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
-
-    pq = pq_ref[b]
-    live = jnp.logical_and(pq >= 0,
-                           jnp.logical_and(i * ps <= pq, pt_ref[b, i] >= 0))
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32).reshape(kt * G, hd) * scale
-        k = k_ref[0].astype(jnp.float32).reshape(kt * ps, hd)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (ktG, ktps)
-        if logit_cap:
-            s = logit_cap * jnp.tanh(s / logit_cap)
-        row_head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // G
-        col_head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) // ps
-        t = i * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) % ps
-        mask = jnp.logical_and(row_head == col_head, t <= pq)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_s[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        # mask p explicitly: a fully-dead row would otherwise see
-        # exp(NEG_INF - NEG_INF) == 1 (NEG_INF is a finite sentinel)
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_s[...] = l_s[...] * corr + p.sum(axis=1, keepdims=True)
-        # cross-head products are exact zeros (p is masked), so the one
-        # (ktG, ktps) × (ktps, hd) value matmul sums only the right block
-        acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
-            p, v_ref[0].astype(jnp.float32).reshape(kt * ps, hd),
-            (((1,), (0,)), ((), ())))
-        m_s[...] = m_new
-
-    @pl.when(i == n_pages - 1)
-    def _finalize():
-        o_ref[0] = (acc_s[...] /
-                    jnp.maximum(l_s[...], 1e-37)
-                    ).reshape(kt, G, hd).astype(o_ref.dtype)
-
-
-def _decode_kernel_mla(pt_ref, pq_ref, q_ref, ckv_ref, kr_ref, o_ref,
-                       m_s, l_s, acc_s, *,
+def _decode_kernel_mla(lay_ref, pt_ref, pq_ref, q_ref, ckv_ref, kr_ref,
+                       o_ref, m_s, l_s, acc_s, *,
                        scale: float, ps: int, n_pages: int):
     """MLA latent flash-decode: grid (batch, page).  The latent cache is
     MQA-shaped — ONE shared latent kv head serves all H absorbed query
@@ -203,8 +153,8 @@ def _decode_kernel_mla(pt_ref, pq_ref, q_ref, ckv_ref, kr_ref, o_ref,
     @pl.when(live)
     def _compute():
         q = q_ref[0].astype(jnp.float32) * scale           # (H, lora+rd)
-        c = ckv_ref[0].astype(jnp.float32)                 # (ps, lora)
-        r = kr_ref[0].astype(jnp.float32)                  # (ps, rd)
+        c = ckv_ref[0, 0].astype(jnp.float32)              # (ps, lora)
+        r = kr_ref[0, 0].astype(jnp.float32)               # (ps, rd)
         k = jnp.concatenate([c, r], axis=1)                # (ps, lora+rd)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (H, ps)
         t = i * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -245,109 +195,105 @@ def _page_block(b, i, pt_ref, pq_ref, ps: int):
     return jnp.maximum(entry, 0)
 
 
-def group_tile(K: int, G: int) -> int:
+def lane_tile(K: int, hd: int) -> int:
+    """The narrowest head tile a page block may have: the smallest divisor
+    of K whose ``kt·hd`` columns fill whole 128-lane rows, else all K
+    (the block is then the pool's whole row)."""
+    return next((d for d in range(1, K + 1)
+                 if K % d == 0 and d * hd % LANES == 0), K)
+
+
+def group_tile(K: int, G: int, hd: int) -> int:
     """kv heads per grouped-grid tile: the largest divisor of K keeping
-    the tile's query rows (kt·G) within one MXU band (8 rows).  Small
-    groups pack several kv heads per matmul; G >= 8 tiles one kv head at
-    a time — still grouped (shared scratch, one matmul per page), just
-    without cross-head packing."""
-    kt = 1
-    for d in range(1, K + 1):
-        if K % d == 0 and d * G <= max(G, 8):
-            kt = d
-    return kt
+    the tile's query rows (kt·G) within one MXU band (8 rows), raised to
+    a multiple of :func:`lane_tile` so its columns fill whole lane rows.
+    Small groups pack several kv heads per matmul; G >= 8 tiles as few
+    kv heads as the lanes allow."""
+    kt = max(d for d in range(1, K + 1)
+             if K % d == 0 and d * G <= max(G, 8))
+    lane = lane_tile(K, hd)
+    return next(d for d in range(kt, K + 1) if K % d == 0 and d % lane == 0)
 
 
 def paged_layout(B: int, K: int, G: int, hd: int, ps: int, pps: int,
-                 n_pool: int, *, grouped: bool) -> KernelLayout:
-    """Grid layout of the flash-decode kernel (both variants).  The
-    ``pallas_call`` below is built from this; ``staticcheck.kernel_check``
-    abstractly evaluates the same index maps over adversarial page
-    tables.  Page-table and position operands are scalar-prefetched and
-    therefore not listed as blocked inputs."""
-    if grouped:
-        kt = group_tile(K, G)
+                 n_pool: int, *, n_layers: int = 1,
+                 grouped: bool = True) -> KernelLayout:
+    """Grid layout of the flash-decode kernel.  The ``pallas_call`` below
+    is built from this; ``staticcheck.kernel_check`` abstractly evaluates
+    the same index maps over adversarial page tables.  The layer index,
+    page-table and position operands are scalar-prefetched and therefore
+    not listed as blocked inputs; q and o are the tiles' block-diagonal
+    query rows and their (kt·G, kt·hd) value rows."""
+    kt = group_tile(K, G, hd) if grouped else lane_tile(K, hd)
+    n_t = K // kt
 
-        def kv_map_g(b, t, i, pt, pq):
-            return (_page_block(b, i, pt, pq, ps), t, 0, 0)
+    def kv_map(b, t, i, lay, pt, pq):
+        return (lay[0], _page_block(b, i, pt, pq, ps), 0, t)
 
-        def q_map_g(b, t, i, pt, pq):
-            return (b, t, 0, 0)
+    def q_map(b, t, i, lay, pt, pq):
+        return (b, t, 0, 0)
 
-        return KernelLayout(
-            name="paged_decode_grouped",
-            grid=(B, K // kt, pps),
-            num_scalar_prefetch=2,
-            in_specs=(
-                SpecDesc("q", (B, K, G, hd), (1, kt, G, hd), q_map_g),
-                SpecDesc("k_pages", (n_pool, K, ps, hd), (1, kt, ps, hd),
-                         kv_map_g),
-                SpecDesc("v_pages", (n_pool, K, ps, hd), (1, kt, ps, hd),
-                         kv_map_g),
-            ),
-            out_specs=(
-                SpecDesc("o", (B, K, G, hd), (1, kt, G, hd), q_map_g),),
-            scratch=(((kt * G, 1), jnp.float32),
-                     ((kt * G, 1), jnp.float32),
-                     ((kt * G, hd), jnp.float32)),
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        )
-
-    def kv_map(b, h, i, pt, pq):
-        return (_page_block(b, i, pt, pq, ps), h, 0, 0)
-
-    def q_map(b, h, i, pt, pq):
-        return (b, h, 0, 0)
-
+    rows = (B, n_t, kt * G, kt * hd)
     return KernelLayout(
         name="paged_decode",
-        grid=(B, K, pps),
-        num_scalar_prefetch=2,
+        grid=(B, n_t, pps),
+        num_scalar_prefetch=3,
         in_specs=(
-            SpecDesc("q", (B, K, G, hd), (1, 1, G, hd), q_map),
-            SpecDesc("k_pages", (n_pool, K, ps, hd), (1, 1, ps, hd), kv_map),
-            SpecDesc("v_pages", (n_pool, K, ps, hd), (1, 1, ps, hd), kv_map),
+            SpecDesc("q", rows, (1, 1, kt * G, kt * hd), q_map),
+            SpecDesc("k_pages", (n_layers, n_pool, ps, K * hd),
+                     (1, 1, ps, kt * hd), kv_map),
+            SpecDesc("v_pages", (n_layers, n_pool, ps, K * hd),
+                     (1, 1, ps, kt * hd), kv_map),
         ),
-        out_specs=(SpecDesc("o", (B, K, G, hd), (1, 1, G, hd), q_map),),
-        scratch=(((G, 1), jnp.float32),
-                 ((G, 1), jnp.float32),
-                 ((G, hd), jnp.float32)),
+        out_specs=(SpecDesc("o", rows, (1, 1, kt * G, kt * hd), q_map),),
+        scratch=(((kt * G, 1), jnp.float32),
+                 ((kt * G, 1), jnp.float32),
+                 ((kt * G, kt * hd), jnp.float32)),
         dimension_semantics=("parallel", "parallel", "arbitrary"),
     )
 
 
+def _block_diag(q: jax.Array, kt: int) -> jax.Array:
+    """(B, K, G, hd) queries → (B, K/kt, kt·G, kt·hd) tiles: query head
+    ``r`` of a tile keeps its vector in kv head ``r // G``'s columns and
+    zeros elsewhere."""
+    B, K, G, hd = q.shape
+    q = q.reshape(B, K // kt, kt, G, 1, hd)
+    own = jnp.eye(kt, dtype=bool)[:, None, :, None]            # (kt,1,kt,1)
+    q = jnp.where(own, q, jnp.zeros((), q.dtype))        # (B,n,kt,G,kt,hd)
+    return q.reshape(B, K // kt, kt * G, kt * hd)
+
+
+def _own_heads(o: jax.Array, kt: int, G: int, hd: int) -> jax.Array:
+    """(B, K/kt, kt·G, kt·hd) value rows → (B, K, G, hd): each query
+    head's columns of its own kv head."""
+    B, n_t = o.shape[:2]
+    o = o.reshape(B, n_t, kt, G, kt, hd)
+    o = jnp.stack([o[:, :, h, :, h] for h in range(kt)], axis=2)
+    return o.reshape(B, n_t * kt, G, hd)
+
+
 def paged_decode_attention(
     q: jax.Array,            # (B, K, G, hd)
-    k_pages: jax.Array,      # (P, K, ps, hd)
-    v_pages: jax.Array,      # (P, K, ps, hd)
+    k_pages: jax.Array,      # (L, P, ps, K·hd)
+    v_pages: jax.Array,      # (L, P, ps, K·hd)
     page_table: jax.Array,   # (B, pps) int32; -1 = unallocated
     pos_q: jax.Array,        # (B,) int32; -1 = inactive slot
+    layer: jax.Array,        # () int32: the stack's layer to walk
     *,
     scale: float,
     logit_cap: float = 0.0,
     interpret: bool = False,
-    grouped: "bool | None" = None,
+    grouped: bool = True,
 ) -> jax.Array:
     B, K, G, hd = q.shape
-    ps = k_pages.shape[2]
+    L, n_pool, ps, _ = k_pages.shape
     pps = page_table.shape[1]
-
-    # the grouped grid tiles head batches to MXU-friendly sizes for every
-    # G (see group_tile), so it is the default; grouped=False keeps the
-    # per-kv-head grid for A/B numerics checks
-    if grouped is None:
-        grouped = True
-    layout = paged_layout(B, K, G, hd, ps, pps, k_pages.shape[0],
+    layout = paged_layout(B, K, G, hd, ps, pps, n_pool, n_layers=L,
                           grouped=grouped)
-    if grouped:
-        kernel = functools.partial(
-            _decode_kernel_grouped, scale=scale, logit_cap=logit_cap,
-            ps=ps, n_pages=pps)
-    else:
-        kernel = functools.partial(
-            _decode_kernel, scale=scale, logit_cap=logit_cap, ps=ps,
-            n_pages=pps)
-
+    kt = layout.in_specs[1].block[-1] // hd
+    kernel = functools.partial(_decode_kernel, scale=scale,
+                               logit_cap=logit_cap, ps=ps, n_pages=pps)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=layout.num_scalar_prefetch,
         grid=layout.grid,
@@ -355,29 +301,32 @@ def paged_decode_attention(
         out_specs=layout.out_block_specs()[0],
         scratch_shapes=layout.scratch_shapes(),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=layout.out_shape_structs([q.dtype])[0],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=layout.dimension_semantics),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), pos_q.astype(jnp.int32), q,
-      k_pages, v_pages)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      page_table.astype(jnp.int32), pos_q.astype(jnp.int32),
+      _block_diag(q, kt), k_pages, v_pages)
+    return _own_heads(out, kt, G, hd)
 
 
 def paged_decode_jnp(
     q: jax.Array,            # (B, K, G, hd)
-    k_pages: jax.Array,      # (P, K, ps, hd)
-    v_pages: jax.Array,      # (P, K, ps, hd)
+    k_pages: jax.Array,      # (L, P, ps, K·hd)
+    v_pages: jax.Array,      # (L, P, ps, K·hd)
     page_table: jax.Array,   # (B, pps) int32; -1 = unallocated
     pos_q: jax.Array,        # (B,) int32; -1 = inactive slot
+    layer: jax.Array,        # () int32: the stack's layer to walk
     *,
     scale: float,
     logit_cap: float = 0.0,
 ) -> jax.Array:
     """Same contract as the kernel, pure jnp: ``lax.scan`` over logical
-    pages carrying (m, l, acc) — transient memory is one (B, K, ps, hd)
+    pages carrying (m, l, acc) — transient memory is one (B, ps, K·hd)
     page gather per step, not the (B, pps·ps, K, hd) view."""
     B, K, G, hd = q.shape
     ps = k_pages.shape[2]
@@ -389,12 +338,11 @@ def paged_decode_jnp(
         m, l, acc = carry
         entry = jax.lax.dynamic_index_in_dim(page_table, i, axis=1,
                                              keepdims=False)     # (B,)
-        # fill-mode gather: -1 is out of bounds -> zeros, page 0 untouched
-        kb = jnp.take(k_pages, entry, axis=0, mode="fill",
-                      fill_value=0).astype(jnp.float32)          # (B,K,ps,hd)
-        vb = jnp.take(v_pages, entry, axis=0, mode="fill",
-                      fill_value=0).astype(jnp.float32)
-        s = jnp.einsum("bkgd,bktd->bkgt", qf, kb)                # (B,K,G,ps)
+        kb = k_pages.at[layer, entry].get(mode="fill", fill_value=0)
+        vb = v_pages.at[layer, entry].get(mode="fill", fill_value=0)
+        kb = kb.reshape(B, ps, K, hd).astype(jnp.float32)
+        vb = vb.reshape(B, ps, K, hd).astype(jnp.float32)
+        s = jnp.einsum("bkgd,btkd->bkgt", qf, kb)                # (B,K,G,ps)
         if logit_cap:
             s = logit_cap * jnp.tanh(s / logit_cap)
         t = i * ps + jnp.arange(ps, dtype=jnp.int32)
@@ -405,7 +353,7 @@ def paged_decode_jnp(
                       jnp.exp(s - m_new[..., None]), 0.0)
         corr = jnp.exp(m - m_new)
         l = l * corr + p.sum(axis=-1)
-        acc = acc * corr[..., None] + jnp.einsum("bkgt,bktd->bkgd", p, vb)
+        acc = acc * corr[..., None] + jnp.einsum("bkgt,btkd->bkgd", p, vb)
         return (m_new, l, acc), None
 
     m0 = jnp.full((B, K, G), NEG_INF, jnp.float32)
@@ -417,25 +365,28 @@ def paged_decode_jnp(
 
 
 def mla_paged_layout(B: int, H: int, lora: int, rd: int, ps: int,
-                     pps: int, n_pool: int) -> KernelLayout:
+                     pps: int, n_pool: int, *,
+                     n_layers: int = 1) -> KernelLayout:
     """Grid layout of the MLA latent flash-decode kernel.  The latent
     pool has no kv-head axis (MQA-shaped), so the grid is just
     (batch, page); the page index maps reuse :func:`_page_block` and get
     the same adversarial-table walk in ``staticcheck.kernel_check``."""
-    def kv_map(b, i, pt, pq):
-        return (_page_block(b, i, pt, pq, ps), 0, 0)
+    def kv_map(b, i, lay, pt, pq):
+        return (lay[0], _page_block(b, i, pt, pq, ps), 0, 0)
 
-    def q_map(b, i, pt, pq):
+    def q_map(b, i, lay, pt, pq):
         return (b, 0, 0)
 
     return KernelLayout(
         name="mla_paged_decode",
         grid=(B, pps),
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         in_specs=(
             SpecDesc("q_lat", (B, H, lora + rd), (1, H, lora + rd), q_map),
-            SpecDesc("ckv_pages", (n_pool, ps, lora), (1, ps, lora), kv_map),
-            SpecDesc("krope_pages", (n_pool, ps, rd), (1, ps, rd), kv_map),
+            SpecDesc("ckv_pages", (n_layers, n_pool, ps, lora),
+                     (1, 1, ps, lora), kv_map),
+            SpecDesc("krope_pages", (n_layers, n_pool, ps, rd),
+                     (1, 1, ps, rd), kv_map),
         ),
         out_specs=(SpecDesc("o", (B, H, lora), (1, H, lora), q_map),),
         scratch=(((H, 1), jnp.float32),
@@ -447,10 +398,11 @@ def mla_paged_layout(B: int, H: int, lora: int, rd: int, ps: int,
 
 def mla_paged_decode_attention(
     q_lat: jax.Array,        # (B, H, lora + rd) absorbed query
-    ckv_pages: jax.Array,    # (P, ps, lora)
-    krope_pages: jax.Array,  # (P, ps, rd)
+    ckv_pages: jax.Array,    # (L, P, ps, lora)
+    krope_pages: jax.Array,  # (L, P, ps, rd)
     page_table: jax.Array,   # (B, pps) int32; -1 = unallocated
     pos_q: jax.Array,        # (B,) int32; -1 = inactive slot
+    layer: jax.Array,        # () int32: the stack's layer to walk
     *,
     scale: float,
     interpret: bool = False,
@@ -461,12 +413,12 @@ def mla_paged_decode_attention(
     holes cost no DMA, dead tails repeat the last live page, inactive
     rows come back zero."""
     B, H, qd = q_lat.shape
-    ps, lora = ckv_pages.shape[1], ckv_pages.shape[2]
-    rd = krope_pages.shape[2]
+    L, n_pool, ps, lora = ckv_pages.shape
+    rd = krope_pages.shape[-1]
     assert qd == lora + rd, (qd, lora, rd)
     pps = page_table.shape[1]
 
-    layout = mla_paged_layout(B, H, lora, rd, ps, pps, ckv_pages.shape[0])
+    layout = mla_paged_layout(B, H, lora, rd, ps, pps, n_pool, n_layers=L)
     kernel = functools.partial(_decode_kernel_mla, scale=scale, ps=ps,
                                n_pages=pps)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -483,16 +435,18 @@ def mla_paged_decode_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=layout.dimension_semantics),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), pos_q.astype(jnp.int32), q_lat,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      page_table.astype(jnp.int32), pos_q.astype(jnp.int32), q_lat,
       ckv_pages, krope_pages)
 
 
 def mla_paged_decode_jnp(
     q_lat: jax.Array,        # (B, H, lora + rd) absorbed query
-    ckv_pages: jax.Array,    # (P, ps, lora)
-    krope_pages: jax.Array,  # (P, ps, rd)
+    ckv_pages: jax.Array,    # (L, P, ps, lora)
+    krope_pages: jax.Array,  # (L, P, ps, rd)
     page_table: jax.Array,   # (B, pps) int32; -1 = unallocated
     pos_q: jax.Array,        # (B,) int32; -1 = inactive slot
+    layer: jax.Array,        # () int32: the stack's layer to walk
     *,
     scale: float,
 ) -> jax.Array:
@@ -500,7 +454,7 @@ def mla_paged_decode_jnp(
     ``lax.scan`` over logical pages carrying (m, l, acc) — transient
     memory is one (B, ps, lora + rd) page gather per step."""
     B, H, _ = q_lat.shape
-    ps, lora = ckv_pages.shape[1], ckv_pages.shape[2]
+    ps, lora = ckv_pages.shape[2], ckv_pages.shape[3]
     pps = page_table.shape[1]
     qf = q_lat.astype(jnp.float32) * scale
     pq = pos_q.astype(jnp.int32)
@@ -509,10 +463,10 @@ def mla_paged_decode_jnp(
         m, l, acc = carry
         entry = jax.lax.dynamic_index_in_dim(page_table, i, axis=1,
                                              keepdims=False)     # (B,)
-        cb = jnp.take(ckv_pages, entry, axis=0, mode="fill",
-                      fill_value=0).astype(jnp.float32)          # (B,ps,lora)
-        rb = jnp.take(krope_pages, entry, axis=0, mode="fill",
-                      fill_value=0).astype(jnp.float32)          # (B,ps,rd)
+        cb = ckv_pages.at[layer, entry].get(
+            mode="fill", fill_value=0).astype(jnp.float32)       # (B,ps,lora)
+        rb = krope_pages.at[layer, entry].get(
+            mode="fill", fill_value=0).astype(jnp.float32)       # (B,ps,rd)
         kb = jnp.concatenate([cb, rb], axis=-1)                  # (B,ps,l+r)
         s = jnp.einsum("bhe,bte->bht", qf, kb)                   # (B,H,ps)
         t = i * ps + jnp.arange(ps, dtype=jnp.int32)

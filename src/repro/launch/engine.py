@@ -225,29 +225,26 @@ def _set_page_tables(cache, host_table: np.ndarray):
         return jax.tree.unflatten(treedef, out)
 
 
-def _copy_pool_pages(cache, pairs: List[Tuple[int, int]]):
+def _copy_pool_pages(cache, srcs, dsts):
     """Device-side ``src -> dst`` page copies in every layer's K/V pool —
     the copy half of copy-on-write: a sequence diverging mid-page from a
     cached prefix gets the partially-shared page duplicated into its own
     private page, then the chunk prefill overwrites the divergent tail
     slots.  Scanned-group pool leaves carry a leading layers dim, so the
     pages axis is 1 there and 0 on unrolled leaves (mirrors
-    ``models.model._slot_axis``)."""
+    ``models.model._slot_axis``).  ``dsts`` past the pool are dropped."""
     import jax
-    import jax.numpy as jnp
 
-    srcs = jnp.asarray([s for s, _ in pairs], jnp.int32)
-    dsts = jnp.asarray([d for _, d in pairs], jnp.int32)
+    from repro.models.model import _POOL_LEAVES
+
     leaves, treedef = jax.tree_util.tree_flatten_with_path(cache)
     out = []
     for path, leaf in leaves:
-        if getattr(path[-1], "key", None) in ("k_pages", "v_pages",
-                                              "ckv_pages", "krope_pages"):
-            ax = 1 if any(getattr(p, "key", None) == "groups"
-                          for p in path) else 0
-            vals = jnp.take(leaf, srcs, axis=ax)
-            leaf = leaf.at[dsts].set(vals) if ax == 0 \
-                else leaf.at[:, dsts].set(vals)
+        if getattr(path[-1], "key", None) in _POOL_LEAVES:
+            if any(getattr(p, "key", None) == "groups" for p in path):
+                leaf = leaf.at[:, dsts].set(leaf[:, srcs], mode="drop")
+            else:
+                leaf = leaf.at[dsts].set(leaf[srcs], mode="drop")
         out.append(leaf)
     return jax.tree.unflatten(treedef, out)
 
@@ -297,7 +294,7 @@ class ServingEngine:
     machine.  See the module docstring for the state-machine contract."""
 
     def __init__(self, cfg, ctx, params, sv: ServeSpec):
-        import jax.numpy as jnp  # noqa: F401  (fail fast without jax)
+        import jax
 
         from repro.configs.base import GLOBAL_ATTN
         from repro.models.model import init_cache, num_pages
@@ -343,6 +340,7 @@ class ServingEngine:
             raise ValueError(f"--overcommit {self.overcommit} must be >= 1")
 
         self.prefill, self.decode = make_serve_steps(cfg, ctx)
+        self._copy_pages = jax.jit(_copy_pool_pages, donate_argnums=(0,))
         from repro.launch.specs import src_len_for
         self.src_len = src_len_for(cfg, self.max_len)
         self.cache = self._on_mesh(init_cache(
@@ -599,8 +597,14 @@ class ServingEngine:
             with spans.span("engine.prefill", slots=1, width=width,
                             tokens=len(tail)) as sp:
                 if cow_pairs:
-                    # every copy of the round, before its first prefill
-                    self.cache = _copy_pool_pages(self.cache, cow_pairs)
+                    # every copy of the round, before its first prefill,
+                    # in place; padded to B pairs, one compile
+                    src = np.zeros(self.B, np.int32)
+                    dst = np.full(self.B, self.pool.n_pages, np.int32)
+                    src[:len(cow_pairs)], dst[:len(cow_pairs)] = \
+                        zip(*cow_pairs)
+                    self.cache = self._copy_pages(
+                        self.cache, jnp.asarray(src), jnp.asarray(dst))
                     cow_pairs = []
                 toks = np.zeros((1, width), tail.dtype)
                 toks[0, :len(tail)] = tail

@@ -136,13 +136,25 @@ def decode_attention_jnp(
     return out.reshape(B, 1, H, vd).astype(q.dtype)
 
 
+def _gather_pages(pages: jax.Array, layer, page_table: jax.Array,
+                  K: int) -> jax.Array:
+    """The table-bounded view of layer ``layer`` of a token-major pool
+    stack, (B, K, pps·ps, d).  Unallocated entries are masked by every
+    caller, so what they gather is never read."""
+    B, pps = page_table.shape
+    ps = pages.shape[2]
+    g = pages.at[layer, page_table].get(mode="fill", fill_value=0)
+    return g.reshape(B, pps * ps, K, -1).transpose(0, 2, 1, 3)
+
+
 def prefill_attention_paged(
     q: jax.Array,            # (B, S0, H, hd) chunk queries, rotated
-    k_pages: jax.Array,      # (P, K, page_size, hd) shared physical pool
-    v_pages: jax.Array,      # (P, K, page_size, vd)
+    k_pages: jax.Array,      # (L, P, page_size, K·hd) token-major pool stack
+    v_pages: jax.Array,      # (L, P, page_size, K·vd)
     page_table: jax.Array,   # (B, pages_per_seq) int32; -1 = unallocated
     pos_q: jax.Array,        # (B, S0) absolute positions of the chunk queries
     lengths: jax.Array,      # (B,) valid chunk tokens; 0 = inactive row
+    layer: jax.Array,        # () int32: the stack's layer to read
     *,
     scale: float,
     logit_cap: float = 0.0,
@@ -150,7 +162,7 @@ def prefill_attention_paged(
     """Chunked-prefill attention over the page table (prefix caching).
 
     The chunk's own K/V were already written into the pool
-    (``_write_prefill_paged_offset`` — write-then-read), so one masked walk
+    (``_write_paged`` — write-then-read), so one masked walk
     serves both the *cached prefix* (shared, possibly aliased pages holding
     positions ``< pos_q``) and within-chunk causality: a key at slot ``t``
     of an allocated page is live iff ``t <= pos_q[b, s]``.  Rows with
@@ -163,15 +175,13 @@ def prefill_attention_paged(
     transient (B, S0, K, G, T) score block stays small; a Pallas chunk
     kernel is future work."""
     B, S0, H, hd = q.shape
-    _, K, ps, _ = k_pages.shape
+    ps = k_pages.shape[2]
+    K = k_pages.shape[3] // hd
     G = H // K
     pps = page_table.shape[1]
     T = pps * ps
-    kb = jnp.take(k_pages, page_table, axis=0, mode="fill",
-                  fill_value=0)                      # (B, pps, K, ps, hd)
-    vb = jnp.take(v_pages, page_table, axis=0, mode="fill", fill_value=0)
-    kb = kb.transpose(0, 2, 1, 3, 4).reshape(B, K, T, kb.shape[-1])
-    vb = vb.transpose(0, 2, 1, 3, 4).reshape(B, K, T, vb.shape[-1])
+    kb = _gather_pages(k_pages, layer, page_table, K)          # (B, K, T, hd)
+    vb = _gather_pages(v_pages, layer, page_table, K)
     pos_k = jnp.where(jnp.repeat(page_table >= 0, ps, axis=1),
                       jnp.arange(T, dtype=jnp.int32)[None, :], -1)   # (B, T)
     qg = q.reshape(B, S0, K, G, hd).astype(jnp.float32) * scale
@@ -195,10 +205,11 @@ def prefill_attention_paged(
 
 def decode_attention_paged(
     q: jax.Array,            # (B, 1, H, hd)
-    k_pages: jax.Array,      # (P, K, page_size, hd) shared physical pool
-    v_pages: jax.Array,      # (P, K, page_size, vd)
+    k_pages: jax.Array,      # (L, P, page_size, K·hd) token-major pool stack
+    v_pages: jax.Array,      # (L, P, page_size, K·vd)
     page_table: jax.Array,   # (B, pages_per_seq) int32; -1 = unallocated
     pos_q: jax.Array,        # scalar or (B,) current position per sequence
+    layer: jax.Array,        # () int32: the stack's layer to read
     *,
     scale: float,
     logit_cap: float = 0.0,
@@ -216,18 +227,13 @@ def decode_attention_paged(
     ``kernels.paged_attention`` instead (Pallas flash-decode over the page
     table, or the O(pages) ``lax.scan`` fallback); this walk stays as the
     equivalence oracle and the benchmark baseline."""
-    B = q.shape[0]
-    _, K, ps, hd = k_pages.shape
+    B, _, _, hd = q.shape
+    ps = k_pages.shape[2]
+    K = k_pages.shape[3] // hd
     pps = page_table.shape[1]
-    # fill-mode gather: -1 entries are out of bounds and fill with zeros —
-    # the old clamp-to-0 gathered (and paid the bandwidth of) page 0 for
-    # every unallocated entry
-    kb = jnp.take(k_pages, page_table, axis=0, mode="fill",
-                  fill_value=0)                      # (B, pps, K, ps, hd)
-    vb = jnp.take(v_pages, page_table, axis=0, mode="fill", fill_value=0)
+    kb = _gather_pages(k_pages, layer, page_table, K)          # (B, K, T, hd)
+    vb = _gather_pages(v_pages, layer, page_table, K)
     T = pps * ps
-    kb = kb.transpose(0, 2, 1, 3, 4).reshape(B, K, T, kb.shape[-1])
-    vb = vb.transpose(0, 2, 1, 3, 4).reshape(B, K, T, vb.shape[-1])
     pos_k = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     alloc = jnp.repeat(page_table >= 0, ps, axis=1)  # (B, T)
     pos_k = jnp.where(alloc, pos_k, -1)
@@ -314,11 +320,12 @@ def mla_prefill_attention_paged(
     q_rope: jax.Array,       # (B, S0, H, rd)   — rotated rope queries
     w_kc: jax.Array,         # (lora, H, nope)  — absorbed into the queries
     w_vc: jax.Array,         # (lora, H, vd)    — reads the latent context
-    ckv_pages: jax.Array,    # (P, page_size, lora) shared latent pool
-    krope_pages: jax.Array,  # (P, page_size, rd)
+    ckv_pages: jax.Array,    # (L, P, page_size, lora) latent pool stack
+    krope_pages: jax.Array,  # (L, P, page_size, rd)
     page_table: jax.Array,   # (B, pages_per_seq) int32; -1 = unallocated
     pos_q: jax.Array,        # (B, S0) absolute positions of the chunk queries
     lengths: jax.Array,      # (B,) valid chunk tokens; 0 = inactive row
+    layer: jax.Array,        # () int32: the stack's layer to read
     *,
     scale: float,
     q_block: int = MLA_Q_BLOCK,
@@ -335,7 +342,7 @@ def mla_prefill_attention_paged(
     and within-chunk causality.  Key blocks are whole pages gathered from
     the table (``k_block`` rounded to pages).  Returns (B, S0, H, vd)."""
     B = q_nope.shape[0]
-    ps = ckv_pages.shape[1]
+    ps = ckv_pages.shape[2]
     per = max(1, k_block // ps)                  # pages per key block
     n_k = -(-page_table.shape[1] // per)
     table = jnp.pad(page_table, [(0, 0), (0, n_k * per
@@ -345,8 +352,8 @@ def mla_prefill_attention_paged(
 
     def key_block(j):
         t = jax.lax.dynamic_slice_in_dim(table, j * per, per, axis=1)
-        cb = jnp.take(ckv_pages, t, axis=0, mode="fill", fill_value=0)
-        rb = jnp.take(krope_pages, t, axis=0, mode="fill", fill_value=0)
+        cb = ckv_pages.at[layer, t].get(mode="fill", fill_value=0)
+        rb = krope_pages.at[layer, t].get(mode="fill", fill_value=0)
         pk = jnp.where(jnp.repeat(t >= 0, ps, axis=1),
                        j * kb + jnp.arange(kb, dtype=jnp.int32)[None, :], -1)
         return cb.reshape(B, kb, -1), rb.reshape(B, kb, -1), pk
@@ -389,46 +396,6 @@ def mla_prefill_attention_fresh(
     pos_q = jnp.broadcast_to(jnp.arange(S0, dtype=jnp.int32), (B, S0))
     return _mla_blocked(q_nope, q_rope, w_kc, w_vc, key_block, n_k, kb,
                         pos_q, lengths, scale=scale, q_block=q_block)
-
-
-def mla_decode_attention_paged(
-    q_eff: jax.Array,        # (B, H, lora)
-    q_rope: jax.Array,       # (B, H, rd)
-    ckv_pages: jax.Array,    # (P, page_size, lora)
-    krope_pages: jax.Array,  # (P, page_size, rd)
-    page_table: jax.Array,   # (B, pages_per_seq)
-    pos_q: jax.Array,        # scalar or (B,)
-    *,
-    scale: float,
-) -> jax.Array:
-    """Reference paged MLA decode walk (gather + dense softmax) — the
-    equivalence oracle for the Pallas kernel / scan fallback.  Returns the
-    latent context (B, H, lora); rows with ``pos_q < 0`` return zeros."""
-    B, H, lora = q_eff.shape
-    ps = ckv_pages.shape[1]
-    pps = page_table.shape[1]
-    T = pps * ps
-    cb = jnp.take(ckv_pages, page_table, axis=0, mode="fill",
-                  fill_value=0).reshape(B, T, lora)
-    rb = jnp.take(krope_pages, page_table, axis=0, mode="fill",
-                  fill_value=0).reshape(B, T, krope_pages.shape[-1])
-    pos_k = jnp.where(jnp.repeat(page_table >= 0, ps, axis=1),
-                      jnp.arange(T, dtype=jnp.int32)[None, :], -1)   # (B, T)
-    s = jnp.einsum("bhl,btl->bht", q_eff.astype(jnp.float32),
-                   cb.astype(jnp.float32))
-    s = s + jnp.einsum("bhr,btr->bht", q_rope.astype(jnp.float32),
-                       rb.astype(jnp.float32))
-    s = s * scale
-    pq = jnp.reshape(jnp.broadcast_to(jnp.asarray(pos_q, jnp.int32), (B,)),
-                     (B, 1))
-    valid = (pos_k >= 0) & (pos_k <= pq)                             # (B, T)
-    vm = valid[:, None, :]
-    s = jnp.where(vm, s, NEG_INF)
-    m = s.max(axis=-1, keepdims=True)
-    p = jnp.where(vm, jnp.exp(s - m), 0.0)
-    l = p.sum(axis=-1)
-    ctx_lat = jnp.einsum("bht,btl->bhl", p, cb.astype(jnp.float32))
-    return (ctx_lat / jnp.maximum(l, 1e-37)[..., None]).astype(q_eff.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -508,10 +475,10 @@ def gqa_attention(
                 raise NotImplementedError(
                     "chunked prefix prefill needs the paged global layout")
             assert lengths is not None, "chunked prefill is ragged-only"
-            new_cache = _write_prefill_paged_offset(cache, k, v, lengths, pos)
+            new_cache = _write_paged(cache, k, v, pos, lengths)
+            (kp, vp), layer = _stacks(new_cache, "k_pages", "v_pages")
             out = prefill_attention_paged(
-                q, new_cache["k_pages"], new_cache["v_pages"],
-                new_cache["page_table"], pos, lengths,
+                q, kp, vp, new_cache["page_table"], pos, lengths, layer,
                 scale=scale, logit_cap=cfg.attn_logit_softcap)
         else:
             S = q.shape[1]
@@ -527,8 +494,12 @@ def gqa_attention(
                     q_block=ctx.attn_q_block, kv_block=ctx.attn_kv_block)
             if cache is not None:       # prefill: write the kv cache
                 if "k_pages" in cache:
-                    new_cache = _write_prefill_paged(cache, k, v,
-                                                     lengths=lengths)
+                    # prefill opens at position 0; lockstep rows are whole
+                    lens = jnp.full((B,), S, jnp.int32) if lengths is None \
+                        else lengths
+                    new_cache = _write_paged(
+                        cache, k, v, jnp.broadcast_to(pos[None, :], (B, S)),
+                        lens)
                 elif lengths is not None:
                     if not window:
                         raise NotImplementedError(
@@ -551,22 +522,23 @@ def gqa_attention(
             if "k_pages" in cache:
                 assert not window, \
                     "paged layout covers global layers; local layers ring"
-                new_cache = _update_decode_kv_paged(cache, k, v, pos)
-                kp, vp = new_cache["k_pages"], new_cache["v_pages"]
-                pt = new_cache["page_table"]
                 posb = jnp.broadcast_to(
                     jnp.reshape(jnp.asarray(pos, jnp.int32), (-1,)),
                     (B,))
+                new_cache = _write_paged(cache, k, v, posb[:, None],
+                                         (posb >= 0).astype(jnp.int32))
+                (kp, vp), layer = _stacks(new_cache, "k_pages", "v_pages")
+                pt = new_cache["page_table"]
                 if ctx.use_pallas:
                     from repro.kernels.ops import paged_decode_bhd
                     out = paged_decode_bhd(
-                        q, kp, vp, pt, posb, scale=scale,
+                        q, kp, vp, pt, posb, layer, scale=scale,
                         logit_cap=cfg.attn_logit_softcap)
                 else:
                     # O(pages) lax.scan walk — same contract as the kernel
                     from repro.kernels.paged_attention import paged_decode_jnp
                     out = paged_decode_jnp(
-                        q.reshape(B, K, H // K, hd), kp, vp, pt, posb,
+                        q.reshape(B, K, H // K, hd), kp, vp, pt, posb, layer,
                         scale=scale,
                         logit_cap=cfg.attn_logit_softcap).reshape(B, 1, H, hd)
             else:
@@ -618,64 +590,62 @@ def _write_full_kv(cache: Cache, k, v, pos, window: int) -> Cache:
     return {"k": ck, "v": cv, "pos": cp}
 
 
-@jax.named_scope("kv_write")
-def _write_prefill_paged(cache: Cache, k, v,
-                         lengths: Optional[jax.Array] = None) -> Cache:
-    """Prefill into the paged layout: walk logical pages 0..ceil(S0/ps)-1 of
-    each sequence's page table and write the K/V chunks into the pool.
-    ``k, v`` arrive as (B, S0, K, hd), rotated; prefill always starts at
-    position 0, so the page loop is static.
-
-    ``lengths`` (ragged prefill) masks the walk per row: row ``b`` writes
-    only pages holding tokens ``< lengths[b]`` — rows with length 0 (slots
-    mid-decode in a continuous batch) touch nothing.  Unallocated entries
-    scatter out of bounds and are dropped (the old clamp wrote rows whose
-    table was shorter than the padded batch onto physical page 0)."""
-    kp, vp, pt = cache["k_pages"], cache["v_pages"], cache["page_table"]
-    ps = kp.shape[2]
-    S0 = k.shape[1]
-    k = k.transpose(0, 2, 1, 3)      # (B, K, S0, hd)
-    v = v.transpose(0, 2, 1, 3)
-    oob = jnp.int32(kp.shape[0])     # one past the pool: mode="drop" target
-    for i in range((S0 + ps - 1) // ps):
-        lo, hi = i * ps, min((i + 1) * ps, S0)
-        write = pt[:, i] >= 0
-        if lengths is not None:
-            write = write & (lo < lengths)
-        phys = jnp.where(write, pt[:, i], oob)       # (B,) physical pages
-        kp = kp.at[phys, :, :hi - lo].set(k[:, :, lo:hi].astype(kp.dtype),
-                                          mode="drop")
-        vp = vp.at[phys, :, :hi - lo].set(v[:, :, lo:hi].astype(vp.dtype),
-                                          mode="drop")
-    return {"k_pages": kp, "v_pages": vp, "page_table": pt}
+def _stacks(cache: Cache, *names: str):
+    """The named pools as layer stacks ``(L, P, ps, W)`` and the layer
+    this call addresses: a scanned group passes its whole stacks with the
+    scan index under ``"layer"`` (``models.model.run_stack``); an
+    unrolled layer's own ``(P, ps, W)`` pool is a stack of one."""
+    if "layer" in cache:
+        return tuple(cache[n] for n in names), cache["layer"]
+    return tuple(cache[n][None] for n in names), jnp.zeros((), jnp.int32)
 
 
-@jax.named_scope("kv_write")
-def _write_prefill_paged_offset(cache: Cache, k, v, lengths, pos) -> Cache:
-    """Offset form of :func:`_write_prefill_paged` for chunked prefix
-    prefill: the chunk's token ``s`` of row ``b`` lands at absolute
-    position ``pos[b, s]`` (= the row's first uncached position + s), so
-    the page walk cannot be a static loop — scatter per token instead.
+def _with_stacks(cache: Cache, **pools) -> Cache:
+    """``cache`` with its pools replaced by the written stacks, in the
+    form it gave them (whole stacks, or one layer's pool)."""
+    out = dict(cache)
+    for name, x in pools.items():
+        out[name] = x if "layer" in cache else x[0]
+    return out
 
-    Only tokens ``s < lengths[b]`` write.  The engine's CoW rule
-    guarantees a chunk never writes a *shared* page (the first written
-    page is always a private copy), so scatter targets are unique.
-    Invalid rows / unallocated table entries redirect one past the pool
-    and are dropped (``mode="drop"``)."""
-    kp, vp, pt = cache["k_pages"], cache["v_pages"], cache["page_table"]
-    B, S0 = k.shape[:2]
-    ps = kp.shape[2]
-    pps = pt.shape[1]
-    pidx = pos // ps                                           # (B, S0)
-    entry = jnp.take_along_axis(pt, jnp.clip(pidx, 0, pps - 1), axis=1)
+
+def _slots(page_table: jax.Array, pos: jax.Array, lengths: jax.Array,
+           n_pool: int, ps: int) -> Tuple[jax.Array, jax.Array]:
+    """Where each token of a (B, S0) block of absolute positions lands in
+    the pool: ``(phys, off)``, page and slot, per token.  Tokens at or past
+    ``lengths[b]``, past the table or on an unallocated entry get ``phys``
+    one past the pool, which a ``mode="drop"`` scatter drops — the pool
+    needs no scratch page, so its size stays mesh-divisible."""
+    S0 = pos.shape[1]
+    pps = page_table.shape[1]
+    pidx = pos // ps
+    entry = jnp.take_along_axis(page_table, jnp.clip(pidx, 0, pps - 1),
+                                axis=1)
     valid = (jnp.arange(S0, dtype=jnp.int32)[None, :]
              < lengths.astype(jnp.int32)[:, None]) \
-        & (entry >= 0) & (pidx < pps)
-    phys = jnp.where(valid, entry, jnp.int32(kp.shape[0]))     # (B, S0)
-    off = pos % ps
-    kp = kp.at[phys, :, off].set(k.astype(kp.dtype), mode="drop")
-    vp = vp.at[phys, :, off].set(v.astype(vp.dtype), mode="drop")
-    return {"k_pages": kp, "v_pages": vp, "page_table": pt}
+        & (pos >= 0) & (entry >= 0) & (pidx < pps)
+    return jnp.where(valid, entry, jnp.int32(n_pool)), pos % ps
+
+
+@jax.named_scope("kv_write")
+def _write_paged(cache: Cache, k, v, pos, lengths) -> Cache:
+    """Write K/V rows into the token-major page pool in place: token
+    ``s`` of row ``b`` (``k, v`` arrive as (B, S0, K, hd), rotated) is
+    one row of all its kv heads, ``pool[layer, phys, off, :]``, at
+    absolute position ``pos[b, s]``.  Only tokens ``s < lengths[b]``
+    write (decode: one token, length 0 for inactive slots).  The
+    engine's CoW rule guarantees a chunk never writes a *shared* page
+    (the first written page is always a private copy), so scatter
+    targets are unique."""
+    (kp, vp), layer = _stacks(cache, "k_pages", "v_pages")
+    B, S0 = k.shape[:2]
+    phys, off = _slots(cache["page_table"], pos, lengths, kp.shape[1],
+                       kp.shape[2])
+    kp = kp.at[layer, phys, off].set(
+        k.reshape(B, S0, -1).astype(kp.dtype), mode="drop")
+    vp = vp.at[layer, phys, off].set(
+        v.reshape(B, S0, -1).astype(vp.dtype), mode="drop")
+    return _with_stacks(cache, k_pages=kp, v_pages=vp)
 
 
 @jax.named_scope("kv_write")
@@ -753,75 +723,21 @@ def _update_decode_kv(cache: Cache, k, v, pos, window: int):
     return {"k": ck, "v": cv, "pos": cp}, ck, cv, cp
 
 
-@jax.named_scope("kv_write")
-def _update_decode_kv_paged(cache: Cache, k, v, pos) -> Cache:
-    """Insert one token's K/V into the page pool.  ``k, v`` arrive as
-    (B, 1, K, hd); ``pos`` is scalar or (B,).  Rows with pos < 0 (inactive
-    slots) and unallocated page-table entries scatter out of bounds and are
-    dropped — the pool needs no scratch page, so its size stays mesh-
-    divisible."""
-    kp, vp, pt = cache["k_pages"], cache["v_pages"], cache["page_table"]
-    B = k.shape[0]
-    ps = kp.shape[2]
-    posb = jnp.broadcast_to(jnp.reshape(jnp.asarray(pos, jnp.int32), (-1,)),
-                            (B,))
-    posc = jnp.maximum(posb, 0)
-    entry = jnp.take_along_axis(pt, (posc // ps)[:, None], axis=1)[:, 0]
-    phys = jnp.where((posb >= 0) & (entry >= 0), entry, kp.shape[0])
-    off = posc % ps
-    kp = kp.at[phys, :, off].set(k[:, 0].astype(kp.dtype), mode="drop")
-    vp = vp.at[phys, :, off].set(v[:, 0].astype(vp.dtype), mode="drop")
-    return {"k_pages": kp, "v_pages": vp, "page_table": pt}
-
-
 # ---------------------------------------------------------------------------
 # MLA (DeepSeek-V2)
 # ---------------------------------------------------------------------------
 @jax.named_scope("kv_write")
-def _write_prefill_latent_paged(cache: Cache, ckv, krope, lengths,
-                                pos) -> Cache:
-    """Scatter a prefill chunk's latents into the paged latent pool.
-
-    ``ckv (B, S0, lora)`` / ``krope (B, S0, rd)`` are the compressed
-    latents and rotated rope keys; token ``s`` of row ``b`` lands at
-    absolute position ``pos[b, s]`` (slot ``pos % ps`` of logical page
-    ``pos // ps``).  Only tokens ``s < lengths[b]`` write; invalid rows
-    and unallocated table entries redirect one past the pool and are
-    dropped (``mode="drop"``) — same contract as the GQA writers."""
-    cp, rp, pt = cache["ckv_pages"], cache["krope_pages"], cache["page_table"]
-    B, S0 = ckv.shape[:2]
-    ps = cp.shape[1]
-    pps = pt.shape[1]
-    pidx = pos // ps                                           # (B, S0)
-    entry = jnp.take_along_axis(pt, jnp.clip(pidx, 0, pps - 1), axis=1)
-    valid = (jnp.arange(S0, dtype=jnp.int32)[None, :]
-             < lengths.astype(jnp.int32)[:, None]) \
-        & (entry >= 0) & (pidx < pps)
-    phys = jnp.where(valid, entry, jnp.int32(cp.shape[0]))     # (B, S0)
-    off = pos % ps
-    cp = cp.at[phys, off].set(ckv.astype(cp.dtype), mode="drop")
-    rp = rp.at[phys, off].set(krope.astype(rp.dtype), mode="drop")
-    return {"ckv_pages": cp, "krope_pages": rp, "page_table": pt}
-
-
-@jax.named_scope("kv_write")
-def _update_decode_latent_paged(cache: Cache, ckv, krope, pos) -> Cache:
-    """Insert one token's latent into the page pool.  ``ckv (B, lora)`` /
-    ``krope (B, rd)``; ``pos`` is scalar or (B,).  Rows with pos < 0
-    (inactive slots) and unallocated entries scatter out of bounds and are
-    dropped."""
-    cp, rp, pt = cache["ckv_pages"], cache["krope_pages"], cache["page_table"]
-    B = ckv.shape[0]
-    ps = cp.shape[1]
-    posb = jnp.broadcast_to(jnp.reshape(jnp.asarray(pos, jnp.int32), (-1,)),
-                            (B,))
-    posc = jnp.maximum(posb, 0)
-    entry = jnp.take_along_axis(pt, (posc // ps)[:, None], axis=1)[:, 0]
-    phys = jnp.where((posb >= 0) & (entry >= 0), entry, cp.shape[0])
-    off = posc % ps
-    cp = cp.at[phys, off].set(ckv.astype(cp.dtype), mode="drop")
-    rp = rp.at[phys, off].set(krope.astype(rp.dtype), mode="drop")
-    return {"ckv_pages": cp, "krope_pages": rp, "page_table": pt}
+def _write_latent_paged(cache: Cache, ckv, krope, pos, lengths) -> Cache:
+    """Scatter latents into the paged latent pool in place.  ``ckv (B,
+    S0, lora)`` / ``krope (B, S0, rd)`` are the compressed latents and
+    rotated rope keys; token ``s`` of row ``b`` lands at absolute
+    position ``pos[b, s]`` — same contract as :func:`_write_paged`."""
+    (cp, rp), layer = _stacks(cache, "ckv_pages", "krope_pages")
+    phys, off = _slots(cache["page_table"], pos, lengths, cp.shape[1],
+                       cp.shape[2])
+    cp = cp.at[layer, phys, off].set(ckv.astype(cp.dtype), mode="drop")
+    rp = rp.at[layer, phys, off].set(krope.astype(rp.dtype), mode="drop")
+    return _with_stacks(cache, ckv_pages=cp, krope_pages=rp)
 
 
 def _mla_q(cfg: ModelConfig, p, x, pos) -> Tuple[jax.Array, jax.Array]:
@@ -890,13 +806,13 @@ def mla_attention(
             else lengths.astype(jnp.int32)
         q_nope, q_rope = _mla_q(cfg, p, x, pos)
         k_rope = apply_rope(k_rope, pos, cfg.rope_theta, yarn)
-        new_cache = _write_prefill_latent_paged(
-            cache, ckv, k_rope[:, :, 0], lens, pos_q)
+        new_cache = _write_latent_paged(
+            cache, ckv, k_rope[:, :, 0], pos_q, lens)
         if pos.ndim == 2:
+            (cp, rp), layer = _stacks(new_cache, "ckv_pages", "krope_pages")
             out = mla_prefill_attention_paged(
-                q_nope, q_rope, w_kc, w_vc, new_cache["ckv_pages"],
-                new_cache["krope_pages"], new_cache["page_table"],
-                pos_q, lens, scale=scale)
+                q_nope, q_rope, w_kc, w_vc, cp, rp, new_cache["page_table"],
+                pos_q, lens, layer, scale=scale)
         else:
             out = mla_prefill_attention_fresh(
                 q_nope, q_rope, w_kc, w_vc, ckv, k_rope[:, :, 0], lens,
@@ -935,21 +851,20 @@ def mla_attention(
         pos_r = jnp.reshape(posb, (-1, 1))                # (B, 1) for rope
         q_nope, q_rope = _mla_q(cfg, p, x, pos_r)
         k_rope = apply_rope(k_rope, pos_r, cfg.rope_theta, yarn)
-        new_cache = _update_decode_latent_paged(
-            cache, ckv[:, 0], k_rope[:, 0, 0], posb)
-        cp_pages, rp_pages = new_cache["ckv_pages"], new_cache["krope_pages"]
+        new_cache = _write_latent_paged(
+            cache, ckv, k_rope[:, :, 0], pos_r, (posb >= 0).astype(jnp.int32))
+        (cp, rp), layer = _stacks(new_cache, "ckv_pages", "krope_pages")
         pt = new_cache["page_table"]
         q_eff = jnp.einsum("bshe,lhe->bshl", q_nope, w_kc)  # (B,1,H,lora)
+        q_lat = jnp.concatenate([q_eff[:, 0], q_rope[:, 0]], -1)
         if ctx.use_pallas:
             from repro.kernels.ops import mla_paged_decode_bhd
-            q_lat = jnp.concatenate([q_eff[:, 0], q_rope[:, 0]], -1)
-            ctx_lat = mla_paged_decode_bhd(
-                q_lat, cp_pages, rp_pages, pt, posb, scale=scale)
+            ctx_lat = mla_paged_decode_bhd(q_lat, cp, rp, pt, posb, layer,
+                                           scale=scale)
         else:
             from repro.kernels.paged_attention import mla_paged_decode_jnp
-            q_lat = jnp.concatenate([q_eff[:, 0], q_rope[:, 0]], -1)
-            ctx_lat = mla_paged_decode_jnp(
-                q_lat, cp_pages, rp_pages, pt, posb, scale=scale)
+            ctx_lat = mla_paged_decode_jnp(q_lat, cp, rp, pt, posb, layer,
+                                           scale=scale)
         out = jnp.einsum("bshl,lhe->bshe", ctx_lat[:, None].astype(x.dtype),
                          w_vc.astype(x.dtype))
     else:

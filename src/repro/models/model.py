@@ -7,7 +7,8 @@ enc-dec, frontend stubs) come from :class:`ModelConfig`.
 Layer stacks are grouped for ``jax.lax.scan`` (compile-time & HLO size):
 ``num_layers`` = prefix (unrolled, e.g. DeepSeek first-k-dense) + n_groups ×
 pattern (scanned, stacked weights) + tail (unrolled remainder).  KV caches
-carry a matching leading group dim and are threaded through the scan as xs/ys.
+carry a matching leading group dim: the page pools ride the scan's carry,
+every other cache leaf its xs/ys.
 
 Modes
 -----
@@ -170,6 +171,43 @@ def _aux_init(cfg: ModelConfig, mode: str) -> jax.Array:
 # ---------------------------------------------------------------------------
 # Stacks
 # ---------------------------------------------------------------------------
+#: Cache leaves that are shared physical page pools (paged layout).
+_POOL_LEAVES = ("k_pages", "v_pages", "ckv_pages", "krope_pages")
+
+
+def _split_pools(tree: Optional[Tree]) -> Tuple[Tree, Optional[Tree]]:
+    """``(pools, rest)`` of a cache tree: its page-pool leaves, and every
+    other leaf (the ``layer`` a scanned layer's view carries is dropped),
+    each in ``tree``'s nesting."""
+    if tree is None:
+        return {}, None
+    pools: Tree = {}
+    rest: Tree = {}
+    for k, v in tree.items():
+        if isinstance(v, dict):
+            pools[k], rest[k] = _split_pools(v)
+        elif k in _POOL_LEAVES:
+            pools[k] = v
+        elif k != "layer":
+            rest[k] = v
+    return pools, rest
+
+
+def _join_pools(rest: Tree, pools: Tree, layer=None) -> Tree:
+    """Inverse of :func:`_split_pools`.  With ``layer``, a scanned layer's
+    view: its own leaves, the group's whole pool stacks, and beside them
+    the layer they address (``models.attention._stacks``)."""
+    out = dict(rest)
+    for k, v in pools.items():
+        if isinstance(v, dict):
+            out[k] = _join_pools(rest.get(k, {}), v, layer)
+        else:
+            out[k] = v
+            if layer is not None:
+                out["layer"] = layer
+    return out
+
+
 def _remat(fn, policy: str):
     if policy == "none":
         return fn
@@ -236,27 +274,36 @@ def run_stack(
             if ab_groups is not None:
                 group_axes = P.tree_logical_axes(ab_groups, drop_leading=1)
 
+        # the page pools ride the carry as whole (L, ...) stacks, each
+        # layer writing its rows into its own layer in place; every other
+        # cache leaf (page tables, rings, recurrent state) is xs/ys
+        pools, gcache = _split_pools(gcache)
+
         def body(carry, xs):
-            h, aux = carry
-            gp, gc = xs
+            h, aux, pools = carry
+            gp, gc, layer = xs
             if group_axes is not None:
                 gp = jax.tree.map(lambda w, ax: ctx.constrain(w, ax),
                                   gp, group_axes)
+            pools = dict(pools)
             nc_out = {} if gc is not None else None
             for j, kind in enumerate(pat):
-                c = gc[str(j)] if gc is not None else None
+                c = _join_pools(gc[str(j)], pools[str(j)], layer) \
+                    if gc is not None else None
                 h, nc, a = run_layer(kind, gp[str(j)], h, c)
                 aux = aux + a
                 if nc_out is not None:
-                    nc_out[str(j)] = nc
-            return (h, aux), nc_out
+                    pools[str(j)], nc_out[str(j)] = _split_pools(nc)
+            return (h, aux, pools), nc_out
 
         body = _remat(body, remat_policy)
-        (h, aux_total), g_nc = jax.lax.scan(
-            body, (h, aux_total), (stack["groups"], gcache),
+        n_groups = jax.tree.leaves(stack["groups"])[0].shape[0]
+        (h, aux_total, pools), g_nc = jax.lax.scan(
+            body, (h, aux_total, pools),
+            (stack["groups"], gcache, jnp.arange(n_groups, dtype=jnp.int32)),
             unroll=True if ctx.scan_unroll else 1)
         if new_cache is not None:
-            new_cache["groups"] = g_nc
+            new_cache["groups"] = _join_pools(g_nc, pools)
 
     # ---- tail (unrolled remainder) -----------------------------------------
     if "tail" in stack:
@@ -446,12 +493,13 @@ def _layer_cache_ab(cfg: ModelConfig, kind: str, B: int, S_max: int,
     """Abstract cache (ParamAb reused as shape+axes carrier) for one layer.
 
     ``layout="paged"`` replaces the dense (B, K, S_max, hd) buffer of
-    *global* attention layers with a shared physical page pool plus a
-    per-sequence page table (vLLM-style).  ``page_budget`` is the pool size
-    in pages (default: worst case, B × ceil(S_max/page_size)).  Masked
-    decode writes (inactive slots) scatter out of bounds and are dropped,
-    so the pool carries no scratch page — its size stays divisible by the
-    mesh axes and shards cleanly over ``cache_pages``.  MLA global layers
+    *global* attention layers with a shared physical page pool, token-major
+    ``(P, page_size, K·hd)``, plus a per-sequence page table (vLLM-style).
+    ``page_budget`` is the pool size in pages (default: worst case,
+    B × ceil(S_max/page_size)).  Masked decode writes (inactive slots)
+    scatter out of bounds and are dropped, so the pool carries no scratch
+    page — its size stays divisible by the mesh axes and shards cleanly
+    over ``cache_pages``.  MLA global layers
     page their *latent* cache (compressed latents + rope keys) the same
     way; only ring-buffer (local) caches stay dense — already bounded.
     """
@@ -490,13 +538,16 @@ def _layer_cache_ab(cfg: ModelConfig, kind: str, B: int, S_max: int,
             ps = cfg.page_size
             pps = num_pages(S_max, ps)
             pool = page_budget if page_budget is not None else B * pps
+            # token-major: a token's K (or V) for every kv head is one
+            # lane-dense row, written in place by one row scatter; the
+            # row shards only by whole kv heads
             c["attn"] = {
-                "k_pages": P.ParamAb((pool, K, ps, hd),
-                                     ("cache_pages", "kv_heads", None,
-                                      "head_dim"), "zeros", dt),
-                "v_pages": P.ParamAb((pool, K, ps, hd),
-                                     ("cache_pages", "kv_heads", None,
-                                      "head_dim"), "zeros", dt),
+                "k_pages": P.ParamAb((pool, ps, K * hd),
+                                     ("cache_pages", None, ("kv_heads", hd)),
+                                     "zeros", dt),
+                "v_pages": P.ParamAb((pool, ps, K * hd),
+                                     ("cache_pages", None, ("kv_heads", hd)),
+                                     "zeros", dt),
                 "page_table": P.ParamAb((B, pps), ("cache_batch", None),
                                         "zeros", "int32"),
             }
@@ -625,9 +676,6 @@ def init_cache(cfg: ModelConfig, batch_size: int, max_len: int,
 # batch 1, while the *shared* page pools pass through whole — a prefill
 # run on the view writes only the pages that row's table points to.
 # ---------------------------------------------------------------------------
-_POOL_LEAVES = ("k_pages", "v_pages", "ckv_pages", "krope_pages")
-
-
 def _slot_axis(path) -> int:
     """Batch axis of a cache leaf: scanned group leaves carry a leading
     ``layers`` dim, so their batch dim is 1."""

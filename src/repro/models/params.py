@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.dist.sharding import AxisName
+
 from repro.configs.base import (
     GLOBAL_ATTN,
     LOCAL_ATTN,
@@ -38,7 +40,7 @@ class ParamAb:
     """Abstract parameter: shape + logical axes + init recipe."""
 
     shape: Tuple[int, ...]
-    logical_axes: Tuple[Optional[str], ...]
+    logical_axes: Tuple[AxisName, ...]
     init: str = "fan_in"          # fan_in | zeros | ones | normal:<s> | rglru_lambda | uniform:<lo>:<hi>
     dtype: str = "float32"
 

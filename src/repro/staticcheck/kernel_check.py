@@ -14,14 +14,17 @@ every grid point and proves, per layout:
 * accumulator scratch buffers are float32 (online-softmax / state carry
   precision);
 * the paged-decode page walk — for the GQA pool layouts (grouped
-  head-tile grid and ungrouped, across head-tile shapes including
-  G > 4) and the MLA latent-pool layout alike — evaluated against
-  adversarial page tables (contiguous, mostly-empty, holes inside the
-  live prefix, inactive rows): block indices stay inside the physical
-  pool, ``-1`` holes borrow an already-live page of the *same row*
-  (never physical page 0's bandwidth), and every dead-tail step repeats
-  the previous page so the pipeline issues no new DMA (the NaN-gather /
-  wasted-bandwidth class the flash-decode PR fixed by hand).
+  head-tile grid and the narrowest lane tile, across head-tile shapes
+  including G > 4 and head widths below one lane row) and the MLA
+  latent-pool layout alike — evaluated against adversarial page tables
+  (contiguous, mostly-empty, holes inside the live prefix, inactive
+  rows) on a layer stack: every block reads the prefetched layer, block
+  indices stay inside the physical pool, ``-1`` holes borrow an
+  already-live page of the *same row* (never physical page 0's
+  bandwidth), every dead-tail step repeats the previous page so the
+  pipeline issues no new DMA (the NaN-gather / wasted-bandwidth class
+  the flash-decode PR fixed by hand), and a page block's columns fill
+  whole 128-lane rows or are the pool's whole row.
 """
 from __future__ import annotations
 
@@ -139,13 +142,19 @@ def _paged_tables():
     return pt, pos
 
 
+#: the layer-stack depth and the layer the paged walks are checked on
+N_LAYERS, LAYER = 3, 2
+
+
 def _walk_page_specs(layout, path, pt_np, pos_np, pt, pos, ps, n_pool,
                      points_for) -> List[Finding]:
     """Adversarial page walk over every ``*_pages`` operand of ``layout``.
 
     ``points_for(b, i)`` yields the grid point(s) covering row ``b`` at
-    page-table step ``i`` (several for head-tiled grids).  The physical
-    page is the first block coordinate the index map returns."""
+    page-table step ``i`` (several for head-tiled grids).  The index map
+    sees the prefetched (layer, table, positions); its first block
+    coordinate is the layer, its second the physical page."""
+    import jax.numpy as jnp
     out: List[Finding] = []
 
     def fail(msg: str) -> None:
@@ -156,14 +165,24 @@ def _walk_page_specs(layout, path, pt_np, pos_np, pt, pos, ps, n_pool,
     if not kv:
         fail("no *_pages operand found — page walk unchecked")
         return out
+    lay = jnp.asarray([LAYER], jnp.int32)
     for spec in kv:
+        cols = spec.block[-1]
+        if cols % 128 and cols != spec.shape[-1]:
+            fail(f"{spec.name}: a page block of {cols} columns neither "
+                 f"fills whole 128-lane rows nor is the pool's row of "
+                 f"{spec.shape[-1]}")
         for b in range(B):
             live = {int(e) for e in pt_np[b] if e >= 0}
             last_live = max(int(pos_np[b]), 0) // ps
             prev: dict = {}
             for i in range(pps):
                 for point in points_for(b, i):
-                    page = int(spec.index_map(*point, pt, pos)[0])
+                    idx = spec.index_map(*point, lay, pt, pos)
+                    if int(idx[0]) != LAYER:
+                        fail(f"{spec.name}: row {b} step {i} reads layer "
+                             f"{int(idx[0])}, not the prefetched {LAYER}")
+                    page = int(idx[1])
                     if not 0 <= page < n_pool:
                         fail(f"{spec.name}: row {b} step {i} fetches "
                              f"physical page {page}, outside the pool "
@@ -187,28 +206,31 @@ def _walk_page_specs(layout, path, pt_np, pos_np, pt, pos, ps, n_pool,
 
 def _check_paged() -> List[Finding]:
     import jax.numpy as jnp
-    from repro.kernels.paged_attention import group_tile, paged_layout
+    from repro.kernels.paged_attention import paged_layout
 
     path = "src/repro/kernels/paged_attention.py"
     out: List[Finding] = []
     pt_np, pos_np = _paged_tables()
     pt, pos = jnp.asarray(pt_np), jnp.asarray(pos_np)
+    lay = jnp.asarray([LAYER], jnp.int32)
     B, pps = pt_np.shape
     ps, n_pool = 4, 8
 
-    # (K, G) sweeps the head-tile grid: kt = K (one tile), kt < K
-    # (several tiles per row), and the large-G regime the tiler exists
-    # for (G > 4, kt clamps to 1)
-    for K, G, grouped in ((2, 2, True), (4, 2, True), (4, 8, True),
-                          (2, 2, False)):
-        layout = paged_layout(B=B, K=K, G=G, hd=8, ps=ps, pps=pps,
-                              n_pool=n_pool, grouped=grouped)
-        # structural walk: index maps see the prefetched (pt, pos) operands
+    # (K, G, hd) sweeps the head-tile grid: kt = K (one tile, the whole
+    # row), kt < K (several tiles per row), the large-G regime (G > 4,
+    # the MXU tile is one head, raised to the lane floor at hd = 64),
+    # and the narrowest lane tiles (two heads of 64, one of 128)
+    for K, G, hd, grouped in ((2, 2, 8, True), (4, 2, 64, True),
+                              (4, 8, 64, True), (4, 2, 64, False),
+                              (2, 2, 128, False)):
+        layout = paged_layout(B=B, K=K, G=G, hd=hd, ps=ps, pps=pps,
+                              n_pool=n_pool, n_layers=N_LAYERS,
+                              grouped=grouped)
+        # structural walk: index maps see the prefetched operands
         out += _check_layout(layout, path,
-                             grid_args=lambda p: p + (pt, pos))
-        # both the grouped head-tile grid (B, K//kt, pps) and the
-        # ungrouped grid (B, K, pps) iterate heads in dim 1
-        n_t = K // group_tile(K, G) if grouped else K
+                             grid_args=lambda p: p + (lay, pt, pos))
+        # the head-tile grid (B, K//kt, pps) iterates tiles in dim 1
+        n_t = layout.grid[1]
         out += _walk_page_specs(
             layout, path, pt_np, pos_np, pt, pos, ps, n_pool,
             lambda b, i: [(b, t, i) for t in range(n_t)])
@@ -227,8 +249,10 @@ def _check_mla_paged() -> List[Finding]:
     ps, n_pool = 4, 8
 
     layout = mla_paged_layout(B=B, H=2, lora=8, rd=4, ps=ps, pps=pps,
-                              n_pool=n_pool)
-    out += _check_layout(layout, path, grid_args=lambda p: p + (pt, pos))
+                              n_pool=n_pool, n_layers=N_LAYERS)
+    lay = jnp.asarray([LAYER], jnp.int32)
+    out += _check_layout(layout, path,
+                         grid_args=lambda p: p + (lay, pt, pos))
     # latent grid is (B, pps): one fused block walks both latent pools
     out += _walk_page_specs(
         layout, path, pt_np, pos_np, pt, pos, ps, n_pool,

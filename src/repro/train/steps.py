@@ -185,10 +185,11 @@ def make_decode_step(cfg: ModelConfig, ctx: Ctx):
 
 
 def make_serve_steps(cfg: ModelConfig, ctx: Ctx, *, donate_cache: bool = True):
-    """Jitted (prefill, decode) pair for the serving driver.  The decode
-    cache argument is donated so the page pool / dense buffer is updated
-    in place across the token loop."""
-    prefill = jax.jit(make_prefill_step(cfg, ctx))
-    decode = jax.jit(make_decode_step(cfg, ctx),
-                     donate_argnums=(2,) if donate_cache else ())
+    """Jitted (prefill, decode) pair for the serving driver.  Both donate
+    their cache argument, so the page pools (and dense buffers) are
+    updated in place: across the token loop, and by every admission's
+    prefill, whose one-row view passes the pools whole."""
+    donate = (2,) if donate_cache else ()
+    prefill = jax.jit(make_prefill_step(cfg, ctx), donate_argnums=donate)
+    decode = jax.jit(make_decode_step(cfg, ctx), donate_argnums=donate)
     return prefill, decode

@@ -268,25 +268,26 @@ def test_blocked_mla_prefill_equals_unblocked():
     pos = jnp.arange(S0)[None]
     want = _unblocked(qn, qr, wk, wv, ckv[:, :S0], kr[:, :S0], pos, pos, 0.3)
     np.testing.assert_allclose(got, want, atol=1e-5)
-    # the paged form: a chunk opening at ``start`` over a shuffled table
+    # the paged form: a chunk opening at ``start`` over a shuffled table,
+    # in layer 1 of a two-layer stack whose layer 0 holds other latents
     pages = T // ps
     perm = np.random.default_rng(0).permutation(pages + 3)[:pages]
-    pool_c = jnp.zeros((pages + 3, ps, 32)).at[perm].set(
-        ckv[0].reshape(pages, ps, 32))
-    pool_r = jnp.zeros((pages + 3, ps, 8)).at[perm].set(
-        kr[0].reshape(pages, ps, 8))
+    noise = jax.random.normal(jax.random.key(9), (2, pages + 3, ps, 40))
+    pool_c = noise[..., :32].at[1, perm].set(ckv[0].reshape(pages, ps, 32))
+    pool_r = noise[..., 32:].at[1, perm].set(kr[0].reshape(pages, ps, 8))
     table = jnp.asarray(np.concatenate([perm, [-1, -1]])[None], jnp.int32)
     pos_q = start + jnp.arange(S0)[None]
+    layer = jnp.int32(1)
     got = mla_prefill_attention_paged(qn, qr, wk, wv, pool_c, pool_r, table,
-                                      pos_q, jnp.array([S0]), scale=0.3,
-                                      q_block=16, k_block=16)
+                                      pos_q, jnp.array([S0]), layer,
+                                      scale=0.3, q_block=16, k_block=16)
     want = _unblocked(qn, qr, wk, wv, ckv, kr, jnp.arange(T)[None], pos_q,
                       0.3)
     np.testing.assert_allclose(got, want, atol=1e-5)
     # rows past ``lengths`` (padding, inactive slots) read zeros
     got = mla_prefill_attention_paged(qn, qr, wk, wv, pool_c, pool_r, table,
-                                      pos_q, jnp.array([10]), scale=0.3,
-                                      q_block=16, k_block=16)
+                                      pos_q, jnp.array([10]), layer,
+                                      scale=0.3, q_block=16, k_block=16)
     assert not np.asarray(got[0, 10:]).any()
     np.testing.assert_allclose(got[0, :10], want[0, :10], atol=1e-5)
 
@@ -301,10 +302,11 @@ def test_blocked_mla_prefill_memory_grows_linearly():
         pages = S // ps
         args = [jax.ShapeDtypeStruct(s, jnp.float32) for s in
                 [(1, S, H, nope), (1, S, H, rd), (lora, H, nope),
-                 (lora, H, nope), (pages, ps, lora), (pages, ps, rd)]]
+                 (lora, H, nope), (1, pages, ps, lora), (1, pages, ps, rd)]]
         args += [jax.ShapeDtypeStruct((1, pages), jnp.int32),
                  jax.ShapeDtypeStruct((1, S), jnp.int32),
-                 jax.ShapeDtypeStruct((1,), jnp.int32)]
+                 jax.ShapeDtypeStruct((1,), jnp.int32),
+                 jax.ShapeDtypeStruct((), jnp.int32)]
         f = jax.jit(lambda *a: mla_prefill_attention_paged(*a, scale=0.1))
         return f.lower(*args).compile().memory_analysis().temp_size_in_bytes
 

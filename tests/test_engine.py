@@ -20,9 +20,13 @@ from repro.models.layers import Ctx
 from repro.models.params import init_params
 
 
-def _build(sv: ServeSpec):
+def _build(sv: ServeSpec, head_dim: int = 0):
+    """Reduced qwen3, paged; ``head_dim`` widens its heads (64: a kv head
+    is half a lane row of the token-major pool)."""
     cfg = dataclasses.replace(get_config("qwen3-0.6b").reduced(),
                               cache_layout="paged")
+    if head_dim:
+        cfg = dataclasses.replace(cfg, head_dim=head_dim)
     ctx = Ctx(dtype=jnp.float32)
     params = init_params(cfg, jax.random.key(0))
     return cfg, ctx, params
@@ -51,6 +55,14 @@ def _drive(engine, snap_at=()):
 # Kill-mid-stream / restore: golden-token equivalence
 # ---------------------------------------------------------------------------
 def test_snapshot_restore_golden_tokens():
+    _golden_tokens_survive_restore()
+
+
+def test_snapshot_restore_golden_tokens_hd64():
+    _golden_tokens_survive_restore(head_dim=64)
+
+
+def _golden_tokens_survive_restore(head_dim: int = 0):
     """Run the engine to completion, snapshotting at several boundaries
     (right after the first admission round, mid-decode, near the end).
     A FRESH engine restored from each snapshot must finish with responses
@@ -58,7 +70,7 @@ def test_snapshot_restore_golden_tokens():
     platform's killed-server scenario rests on."""
     sv = ServeSpec(batch=2, prompt_len=16, gen=6, requests=5,
                    page_budget=6, reduced=True)
-    cfg, ctx, params = _build(sv)
+    cfg, ctx, params = _build(sv, head_dim)
 
     golden = ServingEngine(cfg, ctx, params, sv)
     for r in synthesize_requests(cfg, sv, seed=0, ragged=golden.ragged):
@@ -108,6 +120,14 @@ def _two_requests(ps=8):
 
 
 def test_overcommit_evicts_and_loses_nothing():
+    _evict_replay_loses_nothing()
+
+
+def test_overcommit_evicts_and_loses_nothing_hd64():
+    _evict_replay_loses_nothing(head_dim=64)
+
+
+def _evict_replay_loses_nothing(head_dim: int = 0):
     """Page-starved workload: budget 4 pages, two requests needing 3
     worst-case each.  Conservative admission (1.0) serializes them;
     overcommit 2.0 admits both optimistically, hits page exhaustion
@@ -116,7 +136,7 @@ def test_overcommit_evicts_and_loses_nothing():
     conservative run (greedy decode re-prefills deterministically)."""
     sv = ServeSpec(batch=2, prompt_len=8, gen=10, requests=2,
                    page_budget=4, reduced=True)
-    cfg, ctx, params = _build(sv)
+    cfg, ctx, params = _build(sv, head_dim)
 
     conservative = ServingEngine(cfg, ctx, params, sv)
     for r in _two_requests():
@@ -323,13 +343,21 @@ def test_shared_prefix_batch_equals_solo():
 
 
 def test_cow_divergence_mid_page():
+    _cow_divergence_equals_solo()
+
+
+def test_cow_divergence_mid_page_hd64():
+    _cow_divergence_equals_solo(head_dim=64)
+
+
+def _cow_divergence_equals_solo(head_dim: int = 0):
     """Two prompts agreeing through token 19 and diverging at token 20
     (mid-page): the follower attaches the 2 whole shared pages, CoW-copies
     the partially-shared third page (4 of 8 tokens reused), prefills only
     the divergent tail — and answers exactly as its solo run."""
     sv = ServeSpec(batch=2, prompt_len=24, gen=4, requests=2,
                    page_budget=12, reduced=True)
-    cfg, ctx, params = _build(sv)
+    cfg, ctx, params = _build(sv, head_dim)
     base = np.array(jax.random.randint(
         jax.random.key(3), (24,), 0, cfg.vocab_size))
     fork = base.copy()

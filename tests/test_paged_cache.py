@@ -20,7 +20,12 @@ B, S, S0 = 2, 40, 28      # S0 deliberately not a multiple of page_size=8
 
 
 def _setup(arch):
+    """``arch`` reduced; ``<arch>@hd64`` widens its heads to 64 (a kv head
+    is half a lane row of the token-major pool)."""
+    arch, _, hd = arch.partition("@hd")
     cfg = get_config(arch).reduced()
+    if hd:
+        cfg = dataclasses.replace(cfg, head_dim=int(hd))
     ctx = Ctx(dtype=jnp.float32)
     params = init_params(cfg, jax.random.key(0))
     toks = jax.random.randint(jax.random.key(1), (B, S), 0, cfg.vocab_size)
@@ -40,7 +45,8 @@ def _run_serve(cfg, ctx, params, toks):
 
 
 @pytest.mark.parametrize("arch", ["qwen3-0.6b", "gemma2-9b", "qwen2.5-32b",
-                                  "deepseek-v2-236b"])
+                                  "deepseek-v2-236b", "qwen3-0.6b@hd64",
+                                  "granite-moe-1b-a400m@hd64"])
 def test_paged_matches_dense_decode(arch):
     """Decode logits must agree (fp32) between the dense fallback and the
     paged layout.  The paged model path is the O(pages) online-softmax walk
@@ -58,7 +64,7 @@ def test_paged_matches_dense_decode(arch):
 
 
 @pytest.mark.parametrize("arch", ["qwen3-0.6b", "gemma2-9b",
-                                  "deepseek-v2-236b"])
+                                  "deepseek-v2-236b", "qwen3-0.6b@hd64"])
 def test_per_sequence_decode_positions(arch):
     """Continuous batching decodes rows at different positions: an active
     row with a (B,) position vector must produce the same logits as the
@@ -78,6 +84,74 @@ def test_per_sequence_decode_positions(arch):
                                    mode="decode", cache=cache, pos=pos)
         err = float(jnp.abs(logits[0, 0] - lock[0, t - S0 + 1]).max())
         assert err < 1e-5, (arch, t, err)
+
+
+@pytest.mark.parametrize("hd", [16, 64])
+def test_chunked_prefill_bit_exact_across_splits(hd):
+    """Prefix caching writes a prompt's K/V in chunks, each opening where
+    the pages already written end: the row writes of the chunks leave the
+    token-major pool (a layer of a stack) bit for bit as one write of the
+    whole prompt does, wherever the splits fall (mid-page, on a page
+    boundary), and write nothing outside the row's pages."""
+    from repro.models.attention import _write_paged
+
+    L, P, ps, K, S0 = 3, 8, 8, 2, 28
+    rng = np.random.default_rng(5)
+    pools = {n: jnp.asarray(rng.normal(size=(L, P, ps, K * hd)), jnp.float32)
+             for n in ("k_pages", "v_pages")}
+    table = jnp.asarray([[6, 1, 4, 7, -1]], jnp.int32)
+    k = jnp.asarray(rng.normal(size=(1, S0, K, hd)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(1, S0, K, hd)), jnp.float32)
+
+    def write(splits):
+        cache = dict(pools, page_table=table, layer=jnp.int32(2))
+        for a, b in zip((0,) + splits, splits + (S0,)):
+            cache = _write_paged(cache, k[:, a:b], v[:, a:b],
+                                 jnp.arange(a, b)[None],
+                                 jnp.asarray([b - a], jnp.int32))
+        return cache
+
+    whole = write(())
+    for n in pools:
+        got = np.asarray(whole[n][2]).reshape(P, ps, K, hd)
+        src = np.asarray(k if n == "k_pages" else v)[0]
+        for t in range(S0):
+            np.testing.assert_array_equal(
+                got[int(table[0, t // ps]), t % ps], src[t])
+        np.testing.assert_array_equal(np.asarray(whole[n][:2]),
+                                      np.asarray(pools[n][:2]))
+    for splits in ((13,), (16,), (5, 21)):
+        cache = write(splits)
+        for n in pools:
+            np.testing.assert_array_equal(np.asarray(cache[n]),
+                                          np.asarray(whole[n]), splits)
+
+
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "qwen3-0.6b@hd64",
+                                  "deepseek-v2-236b"])
+def test_chunked_prefill_matches_across_splits(arch):
+    """The model's chunked prefill over pages written by earlier chunks
+    gives the whole prompt's last logits, wherever the splits fall, to
+    float32 rounding: the file's 1e-4 (chunks of other widths take other
+    matmul shapes, so the projections round differently, and a stored
+    bfloat16 K may land one unit apart; the writes themselves are pinned
+    bit for bit above)."""
+    cfg, ctx, params, toks = _setup(arch)
+    cfg = dataclasses.replace(cfg, cache_layout="paged")
+
+    def chunks(splits):
+        cache = init_cache(cfg, 1, S)
+        for a, b in zip((0,) + splits, splits + (S0,)):
+            logits, cache, _ = forward(
+                cfg, params, {"tokens": toks[:1, a:b]}, ctx, mode="prefill",
+                cache=cache, lengths=jnp.asarray([b - a], jnp.int32),
+                starts=jnp.asarray([a], jnp.int32))
+        return logits
+
+    whole = chunks(())
+    for splits in ((13,), (16,), (5, 21)):
+        np.testing.assert_allclose(np.asarray(chunks(splits)),
+                                   np.asarray(whole), atol=1e-4)
 
 
 def test_paged_cache_is_smaller_in_specs():

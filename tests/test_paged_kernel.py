@@ -17,14 +17,19 @@ from repro.models.model import forward, init_cache
 from repro.models.params import init_params
 
 RNG = np.random.default_rng(7)
+#: pools are token-major stacks of LAYERS layers; every walk reads LAYER,
+#: so one that addresses another layer reads other random pages
+LAYERS, LAYER = 3, jnp.int32(1)
+
+
+def _stack(P, ps, W):
+    return jnp.asarray(RNG.normal(size=(LAYERS, P, ps, W)), jnp.float32)
 
 
 def _pool(B, K, hd, ps, pps, pool=None):
     P = pool or B * pps
     q = jnp.asarray(RNG.normal(size=(B, 1, 2 * K, hd)), jnp.float32)
-    kp = jnp.asarray(RNG.normal(size=(P, K, ps, hd)), jnp.float32)
-    vp = jnp.asarray(RNG.normal(size=(P, K, ps, hd)), jnp.float32)
-    return q, kp, vp, P
+    return q, _stack(P, ps, K * hd), _stack(P, ps, K * hd), P
 
 
 def _ragged_tables(B, pps, P, live_pages):
@@ -41,13 +46,14 @@ def _ragged_tables(B, pps, P, live_pages):
 # ---------------------------------------------------------------------------
 # Kernel ≡ reference ≡ scan fallback ≡ dense, on ragged tables
 # ---------------------------------------------------------------------------
+@pytest.mark.parametrize("hd", [16, 64])
 @pytest.mark.parametrize("logit_cap", [0.0, 30.0])
-def test_kernel_matches_reference_ragged(logit_cap):
+def test_kernel_matches_reference_ragged(logit_cap, hd):
     """Ragged tables (different live-page counts per row, partially filled
     last page, -1 holes in the tail) with per-sequence pos_q: the Pallas
     kernel (interpret), the lax.scan fallback, and the gather reference
-    agree in fp32 on every active row."""
-    B, K, hd, ps, pps = 4, 2, 16, 8, 6
+    agree in fp32 on every active row, reading one layer of the stack."""
+    B, K, ps, pps = 4, 2, 8, 6
     q, kp, vp, P = _pool(B, K, hd, ps, pps)
     table = _ragged_tables(B, pps, P, [3, 6, 1, 4])
     # row positions: partial last page (19 in page 2 of 3), full table,
@@ -55,11 +61,11 @@ def test_kernel_matches_reference_ragged(logit_cap):
     pos = jnp.asarray([19, 47, 0, -1], jnp.int32)
     kw = dict(scale=hd ** -0.5, logit_cap=logit_cap)
 
-    ref = decode_attention_paged(q, kp, vp, table, pos, **kw)
-    ker = paged_decode_bhd(q, kp, vp, table, pos, **kw)
+    ref = decode_attention_paged(q, kp, vp, table, pos, LAYER, **kw)
+    ker = paged_decode_bhd(q, kp, vp, table, pos, LAYER, **kw)
     H = q.shape[2]
     scan = paged_decode_jnp(q.reshape(B, K, H // K, hd), kp, vp, table, pos,
-                            **kw).reshape(B, 1, H, hd)
+                            LAYER, **kw).reshape(B, 1, H, hd)
     active = slice(0, 3)                       # row 3 is the inactive slot
     np.testing.assert_allclose(np.asarray(ker[active]),
                                np.asarray(ref[active]), atol=2e-6)
@@ -70,32 +76,33 @@ def test_kernel_matches_reference_ragged(logit_cap):
     assert float(jnp.abs(scan[3]).max()) == 0.0
 
 
+@pytest.mark.parametrize("hd", [16, 64, 128])
 @pytest.mark.parametrize("logit_cap", [0.0, 30.0])
-def test_grouped_kernel_matches_ungrouped_and_oracle(logit_cap):
+def test_grouped_kernel_matches_ungrouped_and_oracle(logit_cap, hd):
     """The grouped (head-tiled, one-MXU-call-per-page) variant ≡ the
-    per-kv-head grid ≡ the scan fallback ≡ the gather oracle, on the same
-    ragged tables.  G sweeps both sides of the old ``G <= 4`` auto-cap
-    (since removed — grouped is the default for every G) plus the
-    non-divisor boundary G=5, where the head tile clamps to kt=1."""
+    narrowest lane tile ≡ the scan fallback ≡ the gather oracle, on the
+    same ragged tables.  G sweeps both sides of the old ``G <= 4``
+    auto-cap (since removed — grouped is the default for every G) plus
+    the non-divisor boundary G=5; hd sweeps tiles of several kv heads
+    (16, 64: their block-diagonal queries) and of one (128)."""
     from repro.kernels.paged_attention import paged_decode_attention
 
     for G in (1, 2, 4, 5, 8):
-        B, K, hd, ps, pps = 4, 2, 16, 8, 6
+        B, K, ps, pps = 4, 4, 8, 6
         P = B * pps
         q = jnp.asarray(RNG.normal(size=(B, K, G, hd)), jnp.float32)
-        kp = jnp.asarray(RNG.normal(size=(P, K, ps, hd)), jnp.float32)
-        vp = jnp.asarray(RNG.normal(size=(P, K, ps, hd)), jnp.float32)
+        kp, vp = _stack(P, ps, K * hd), _stack(P, ps, K * hd)
         table = _ragged_tables(B, pps, P, [3, 6, 1, 4])
         pos = jnp.asarray([19, 47, 0, -1], jnp.int32)
         kw = dict(scale=hd ** -0.5, logit_cap=logit_cap)
 
-        grp = paged_decode_attention(q, kp, vp, table, pos, interpret=True,
-                                     grouped=True, **kw)
-        ung = paged_decode_attention(q, kp, vp, table, pos, interpret=True,
-                                     grouped=False, **kw)
-        scan = paged_decode_jnp(q, kp, vp, table, pos, **kw)
+        grp = paged_decode_attention(q, kp, vp, table, pos, LAYER,
+                                     interpret=True, grouped=True, **kw)
+        ung = paged_decode_attention(q, kp, vp, table, pos, LAYER,
+                                     interpret=True, grouped=False, **kw)
+        scan = paged_decode_jnp(q, kp, vp, table, pos, LAYER, **kw)
         ref = decode_attention_paged(
-            q.reshape(B, 1, K * G, hd), kp, vp, table, pos,
+            q.reshape(B, 1, K * G, hd), kp, vp, table, pos, LAYER,
             **kw).reshape(B, K, G, hd)
         act = slice(0, 3)                      # row 3 is the inactive slot
         np.testing.assert_allclose(np.asarray(grp[act]), np.asarray(ung[act]),
@@ -109,28 +116,41 @@ def test_grouped_kernel_matches_ungrouped_and_oracle(logit_cap):
 
 def test_group_tile_and_default_grouped():
     """The head tiler returns the largest divisor of K whose fused block
-    stays within the MXU budget, and ``grouped=None`` now defaults to the
-    grouped grid for every G (the old ``G <= 4`` auto-cap is gone)."""
-    from repro.kernels.paged_attention import group_tile, paged_decode_attention
+    stays within the MXU budget, raised until its columns fill whole
+    128-lane rows of the token-major pool (the whole row where no divisor
+    does); the grouped grid is the default for every G."""
+    from repro.kernels.paged_attention import (
+        group_tile, lane_tile, paged_decode_attention, paged_layout)
 
-    assert group_tile(2, 2) == 2      # whole K fuses: 2·2 ≤ 8
-    assert group_tile(8, 1) == 8
-    assert group_tile(4, 4) == 2      # 4·4 > 8 → tile at 2
-    assert group_tile(2, 5) == 1      # 2·5 > 8 → per-head
-    assert group_tile(2, 8) == 1      # G > budget: one head per tile
-    assert group_tile(3, 4) == 1      # non-divisor G, prime-ish K
+    assert group_tile(2, 2, 128) == 2     # whole K fuses: 2·2 ≤ 8
+    assert group_tile(8, 1, 128) == 8
+    assert group_tile(4, 4, 128) == 2     # 4·4 > 8 → tile at 2
+    assert group_tile(2, 5, 128) == 1     # 2·5 > 8 → per-head
+    assert group_tile(2, 8, 128) == 1     # G > budget: one head per tile
+    assert group_tile(3, 4, 128) == 1     # non-divisor G, prime-ish K
+    assert group_tile(8, 2, 64) == 4      # granite: 256 lanes
+    assert group_tile(8, 2, 128) == 4     # qwen3: 512 lanes
+    assert group_tile(2, 8, 64) == 2      # one head is 64 lanes: take two
+    assert group_tile(3, 4, 16) == 3      # no divisor fills 128: whole row
+    assert lane_tile(8, 128) == 1 and lane_tile(8, 64) == 2
+    assert lane_tile(8, 32) == 4 and lane_tile(1, 64) == 1
+    for K, G, hd in ((8, 2, 64), (8, 2, 128), (2, 8, 64), (4, 2, 128)):
+        for grouped in (True, False):
+            cols = paged_layout(2, K, G, hd, 128, 4, 8,
+                                grouped=grouped).in_specs[1].block[-1]
+            assert cols % 128 == 0 or cols == K * hd, (K, G, hd, cols)
 
     B, K, G, hd, ps, pps = 2, 2, 8, 16, 8, 3
     P = B * pps
     q = jnp.asarray(RNG.normal(size=(B, K, G, hd)), jnp.float32)
-    kp = jnp.asarray(RNG.normal(size=(P, K, ps, hd)), jnp.float32)
-    vp = jnp.asarray(RNG.normal(size=(P, K, ps, hd)), jnp.float32)
+    kp, vp = _stack(P, ps, K * hd), _stack(P, ps, K * hd)
     table = _ragged_tables(B, pps, P, [2, 3])
     pos = jnp.asarray([10, 23], jnp.int32)
     kw = dict(scale=hd ** -0.5, logit_cap=0.0)
-    auto = paged_decode_attention(q, kp, vp, table, pos, interpret=True, **kw)
-    grp = paged_decode_attention(q, kp, vp, table, pos, interpret=True,
-                                 grouped=True, **kw)
+    auto = paged_decode_attention(q, kp, vp, table, pos, LAYER,
+                                  interpret=True, **kw)
+    grp = paged_decode_attention(q, kp, vp, table, pos, LAYER,
+                                 interpret=True, grouped=True, **kw)
     np.testing.assert_array_equal(np.asarray(auto), np.asarray(grp))
 
 
@@ -146,16 +166,17 @@ def test_mla_kernel_matches_scan_and_oracle():
     B, H, lora, rd, ps, pps = 4, 3, 16, 8, 8, 6
     P = B * pps
     q = jnp.asarray(RNG.normal(size=(B, H, lora + rd)), jnp.float32)
-    ckv = jnp.asarray(RNG.normal(size=(P, ps, lora)), jnp.float32)
-    krope = jnp.asarray(RNG.normal(size=(P, ps, rd)), jnp.float32)
+    ckv, krope = _stack(P, ps, lora), _stack(P, ps, rd)
     table = _ragged_tables(B, pps, P, [3, 6, 1, 4])
     pos = jnp.asarray([19, 47, 0, -1], jnp.int32)
     scale = (lora + rd) ** -0.5
 
-    ker = mla_paged_decode_attention(q, ckv, krope, table, pos, scale=scale,
-                                     interpret=True)
-    scan = mla_paged_decode_jnp(q, ckv, krope, table, pos, scale=scale)
-    ops = mla_paged_decode_bhd(q, ckv, krope, table, pos, scale=scale)
+    ker = mla_paged_decode_attention(q, ckv, krope, table, pos, LAYER,
+                                     scale=scale, interpret=True)
+    scan = mla_paged_decode_jnp(q, ckv, krope, table, pos, LAYER,
+                                scale=scale)
+    ops = mla_paged_decode_bhd(q, ckv, krope, table, pos, LAYER,
+                               scale=scale)
 
     # dense oracle: gather each row's live tokens, full softmax in fp64
     tnp, pnp = np.asarray(table), np.asarray(pos)
@@ -168,9 +189,9 @@ def test_mla_kernel_matches_scan_and_oracle():
         for t in range(pnp[b] + 1):
             page = tnp[b, t // ps]
             assert page >= 0
-            ks.append(np.concatenate([np.asarray(ckv[page, t % ps]),
-                                      np.asarray(krope[page, t % ps])]))
-            vs.append(np.asarray(ckv[page, t % ps]))
+            ks.append(np.concatenate([np.asarray(ckv[LAYER, page, t % ps]),
+                                      np.asarray(krope[LAYER, page, t % ps])]))
+            vs.append(np.asarray(ckv[LAYER, page, t % ps]))
         kmat, vmat = np.stack(ks), np.stack(vs)          # (T, lora+rd/lora)
         s = qn[b] @ kmat.T * scale                       # (H, T)
         p = np.exp(s - s.max(-1, keepdims=True))
@@ -199,18 +220,20 @@ def test_kernel_matches_dense_layout():
     kd = np.zeros((B, K, T, hd), np.float32)
     vd = np.zeros((B, K, T, hd), np.float32)
     tnp = np.asarray(table)
+    page = lambda pool, i: np.asarray(pool[LAYER, i]).reshape(
+        ps, K, hd).transpose(1, 0, 2)                    # (K, ps, hd)
     for b in range(B):
         for i in range(pps):
             if tnp[b, i] >= 0:
-                kd[b, :, i * ps:(i + 1) * ps] = np.asarray(kp[tnp[b, i]])
-                vd[b, :, i * ps:(i + 1) * ps] = np.asarray(vp[tnp[b, i]])
+                kd[b, :, i * ps:(i + 1) * ps] = page(kp, tnp[b, i])
+                vd[b, :, i * ps:(i + 1) * ps] = page(vp, tnp[b, i])
     pos_k = np.broadcast_to(np.arange(T, dtype=np.int32), (B, T)).copy()
     for b in range(B):
         pos_k[b, np.repeat(tnp[b] < 0, ps)] = -1
 
     dense = decode_attention_jnp(q, jnp.asarray(kd), jnp.asarray(vd),
                                  jnp.asarray(pos_k), pos, scale=scale)
-    ker = paged_decode_bhd(q, kp, vp, table, pos, scale=scale)
+    ker = paged_decode_bhd(q, kp, vp, table, pos, LAYER, scale=scale)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(dense), atol=2e-6)
 
 
@@ -220,16 +243,16 @@ def test_unallocated_pages_never_gathered():
     out of every table: no walk may touch it."""
     B, K, hd, ps, pps = 2, 2, 16, 8, 4
     q, kp, vp, P = _pool(B, K, hd, ps, pps)
-    kp = kp.at[0].set(jnp.nan)
-    vp = vp.at[0].set(jnp.nan)
+    kp = kp.at[LAYER, 0].set(jnp.nan)
+    vp = vp.at[LAYER, 0].set(jnp.nan)
     table = np.full((B, pps), -1, np.int32)
     table[0, :2] = [3, 5]                      # page 0 unused everywhere
     table[1, :1] = [7]
     table = jnp.asarray(table)
     pos = jnp.asarray([12, 4], jnp.int32)
     kw = dict(scale=hd ** -0.5)
-    for out in (decode_attention_paged(q, kp, vp, table, pos, **kw),
-                paged_decode_bhd(q, kp, vp, table, pos, **kw)):
+    for out in (decode_attention_paged(q, kp, vp, table, pos, LAYER, **kw),
+                paged_decode_bhd(q, kp, vp, table, pos, LAYER, **kw)):
         assert not bool(jnp.isnan(out).any()), "page 0 leaked into the walk"
 
 
@@ -248,8 +271,8 @@ def test_aliased_prefix_pages_match_dealiased_oracle():
                            [0, 1, 4, -1, -1],
                            [0, 1, 5, 6, 7]], jnp.int32)
     # oracle pool: rows 1 and 2 get their own verbatim copies at 8..11
-    kp2 = jnp.concatenate([kp, kp[jnp.asarray([0, 1, 0, 1])]], axis=0)
-    vp2 = jnp.concatenate([vp, vp[jnp.asarray([0, 1, 0, 1])]], axis=0)
+    kp2 = jnp.concatenate([kp, kp[:, jnp.asarray([0, 1, 0, 1])]], axis=1)
+    vp2 = jnp.concatenate([vp, vp[:, jnp.asarray([0, 1, 0, 1])]], axis=1)
     dealiased = jnp.asarray([[0, 1, 2, 3, -1],
                              [8, 9, 4, -1, -1],
                              [10, 11, 5, 6, 7]], jnp.int32)
@@ -257,11 +280,13 @@ def test_aliased_prefix_pages_match_dealiased_oracle():
     kw = dict(scale=hd ** -0.5)
 
     for fn in (
-        lambda k_, v_, t: decode_attention_paged(q, k_, v_, t, pos, **kw),
+        lambda k_, v_, t: decode_attention_paged(q, k_, v_, t, pos, LAYER,
+                                                 **kw),
         lambda k_, v_, t: paged_decode_jnp(
-            q.reshape(B, K, 2, hd), k_, v_, t, pos, **kw),
+            q.reshape(B, K, 2, hd), k_, v_, t, pos, LAYER, **kw),
         lambda k_, v_, t: paged_decode_attention(
-            q.reshape(B, K, 2, hd), k_, v_, t, pos, interpret=True, **kw),
+            q.reshape(B, K, 2, hd), k_, v_, t, pos, LAYER, interpret=True,
+            **kw),
     ):
         shared = fn(kp, vp, aliased)
         oracle = fn(kp2, vp2, dealiased)
